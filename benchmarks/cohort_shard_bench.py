@@ -43,12 +43,12 @@ def _worker(cohorts, rounds: int) -> None:
 
     from repro import trees
     from repro.core.cohort import build_supervised_round
-    from repro.sharding import cohort_sharding
+    from repro.sharding import auto_mesh, cohort_sharding
 
     from benchmarks.fl_engine_bench import _build_workload
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",)) if n_dev > 1 else None
+    mesh = auto_mesh((n_dev,), ("data",)) if n_dev > 1 else None
 
     for n_clients in cohorts:
         local_step, pred, states, batches, weights, steps = _build_workload(
@@ -95,6 +95,9 @@ def _worker(cohorts, rounds: int) -> None:
 
 def _spawn(n_dev: int, cohorts, rounds: int):
     env = dict(os.environ)
+    # forced host devices exist only on the CPU backend, and a child must
+    # never contend for an accelerator its parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_dev} "
                         + env.get("XLA_FLAGS", "")).strip()
     env.setdefault("PYTHONPATH", "src")

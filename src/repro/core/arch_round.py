@@ -1,6 +1,6 @@
 """Generic per-architecture fused federated round — the config-zoo scenario.
 
-``run_arch_round`` runs a reduced FedLoRA-style cohort round on ANY
+``run_arch_round`` runs a FedLoRA-style cohort round on ANY
 ``configs/`` architecture (dense gpt2, MLA deepseek, SSM mamba/jamba, MoE
 dbrx, enc-dec whisper): per-client rank-r LoRA factor trees train through
 ``core/cohort.build_supervised_round`` — one fused vmapped (and optionally
@@ -70,8 +70,10 @@ class ArchRoundConfig:
     local_steps: int = 2
     batch: int = 4
     seq_len: int = 16
-    d_model: int = 64
+    d_model: int = 64      # width of the reduced config
     repeats: int = 1
+    published_widths: bool = False  # run the arch's published config
+                                    # instead of reduced(d_model, repeats)
     lora_rank: int = 4
     lr: float = 1e-3
     seed: int = 0
@@ -113,11 +115,12 @@ def _fold_valid(batch):
 
 def run_arch_round(cfg: ArchRoundConfig, mesh=None,
                    client_axes=None) -> Dict:
-    """Run the fused factored cohort round for one architecture; see the
-    module docstring.  ``mesh`` shards the client axis (ghost-padding
+    """Run the fused factored cohort round for one architecture (reduced,
+    or at its published widths); see the module docstring.  ``mesh`` shards the client axis (ghost-padding
     non-divisible cohorts)."""
-    mcfg = get_config(cfg.arch).reduced(d_model=cfg.d_model,
-                                        repeats=cfg.repeats)
+    mcfg = get_config(cfg.arch)
+    if not cfg.published_widths:
+        mcfg = mcfg.reduced(d_model=cfg.d_model, repeats=cfg.repeats)
     model = Model(mcfg, meshctx=MeshCtx.single_device())
     key = jax.random.PRNGKey(cfg.seed)
     params = model.init(key, max_seq=cfg.seq_len)
@@ -130,9 +133,9 @@ def run_arch_round(cfg: ArchRoundConfig, mesh=None,
              for ci in range(cfg.n_clients)]
     opt = adamw(cfg.lr, update_mask=lambda p: not p.endswith("/mask"))
 
-    def local_step(lora, opt_state, batch):
+    def local_step(base, lora, opt_state, batch):
         def loss_fn(lf):
-            return model.lm_loss(params, _fold_valid(batch), lora=lf,
+            return model.lm_loss(base, _fold_valid(batch), lora=lf,
                                  lora_scale=scale)
         loss, g = jax.value_and_grad(loss_fn)(lora)
         upd, opt_state = opt.update(g, opt_state, lora)
@@ -143,7 +146,7 @@ def run_arch_round(cfg: ArchRoundConfig, mesh=None,
     pad = cs.pad if cs is not None else (lambda xs: list(xs))
     round_step = build_supervised_round(
         local_step, None, mesh=cs.mesh if cs is not None else None,
-        client_axes=cs.axes if cs is not None else None)
+        client_axes=cs.axes if cs is not None else None, base=params)
     cohort = trees.stack(pad(loras))
     cohort_opt = trees.stack(pad([opt.init(l) for l in loras]))
     if cs is not None:
@@ -194,9 +197,9 @@ def run_arch_round(cfg: ArchRoundConfig, mesh=None,
         # legacy dense-merge loop over the IDENTICAL padded batches: one
         # jitted per-client step that materializes W + sAB every call
         @jax.jit
-        def oracle_step(lora, opt_state, batch):
+        def oracle_step(base, lora, opt_state, batch):
             def loss_fn(lf):
-                eff = peft_mod.apply_lora(params, lf, pc)
+                eff = peft_mod.apply_lora(base, lf, pc)
                 return model.lm_loss(eff, _fold_valid(batch))
             loss, g = jax.value_and_grad(loss_fn)(lora)
             upd, opt_state = opt.update(g, opt_state, lora)
@@ -212,7 +215,7 @@ def run_arch_round(cfg: ArchRoundConfig, mesh=None,
                     batch = {k: jnp.asarray(v[ci, si])
                              for k, v in stacked.items()}
                     o_loras[ci], o_opts[ci], loss = oracle_step(
-                        o_loras[ci], o_opts[ci], batch)
+                        params, o_loras[ci], o_opts[ci], batch)
                     max_err = max(max_err, abs(float(loss)
                                                - eng_losses[rnd][ci, si]))
             agg = fedavg_stacked(trees.stack(o_loras),

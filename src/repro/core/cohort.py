@@ -65,6 +65,7 @@ the aggregation exactly.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -81,7 +82,7 @@ from repro.core.aggregation import _pad_mask
 from repro.obs.health import cohort_health
 from repro.rlhf.ppo import PPOConfig, make_ppo_fns
 from repro.rlhf.rollout import generate
-from repro.sharding import client_shard_axes, shard_map
+from repro.sharding import client_shard_axes
 
 
 def _where_clients(mask, new, old):
@@ -204,7 +205,7 @@ def stack_host_batches(per_client_batches):
 
 
 def build_cohort_eval(eval_fn: Callable,
-                      sharding: Optional[NamedSharding] = None):
+                      sharding: Optional[NamedSharding] = None, base=None):
     """Fuse per-client eval into ONE jitted vmapped dispatch per round.
 
     ``eval_fn(trainable, *per_client_data) -> pytree`` is the UNJITTED
@@ -215,21 +216,24 @@ def build_cohort_eval(eval_fn: Callable,
 
     ``sharding`` (client-axis ``NamedSharding``): every stacked input is
     constrained to the client sharding, so GSPMD keeps the vmapped eval
-    device-parallel over the mesh instead of gathering the cohort."""
-    f = jax.vmap(eval_fn)
-    if sharding is None:
-        return jax.jit(f)
-    spec = tuple(sharding.spec)
+    device-parallel over the mesh instead of gathering the cohort.
 
+    ``base`` (the frozen model, unbatched): handed to
+    ``eval_fn(base, trainable, *per_client_data)`` as an argument of the
+    compiled eval — see ``build_supervised_round``."""
     def constrain(x):
+        spec = tuple(sharding.spec)
         full = P(*(spec + (None,) * (x.ndim - len(spec))))
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(sharding.mesh, full))
 
-    def cohort_eval(*args):
-        return f(*jax.tree_util.tree_map(constrain, args))
+    def cohort_eval(b, *args):
+        if sharding is not None:
+            args = jax.tree_util.tree_map(constrain, args)
+        fn = eval_fn if base is None else functools.partial(eval_fn, b)
+        return jax.vmap(fn)(*args)
 
-    return jax.jit(cohort_eval)
+    return functools.partial(jax.jit(cohort_eval), base)
 
 
 def build_supervised_round(local_step_fn: Callable,
@@ -238,7 +242,7 @@ def build_supervised_round(local_step_fn: Callable,
                            client_axes=None, codec=None,
                            factored_agg: bool = False,
                            robust: bool = False, min_quorum: int = 0,
-                           health: bool = False):
+                           health: bool = False, base=None):
     """Fuse per-client local SGD + FedAvg + broadcast into one jitted step.
 
     ``local_step_fn(trainable, opt_state, batch) -> (trainable, opt_state,
@@ -296,23 +300,35 @@ def build_supervised_round(local_step_fn: Callable,
     training-health scalars (``repro.obs.health.cohort_health``) computed
     inside the same compiled body, so the round still costs exactly one
     dispatch and the factored path is untouched.
+
+    ``base`` (the frozen model, replicated): handed to
+    ``local_step_fn(base, trainable, opt_state, batch)`` as an ARGUMENT of
+    the compiled round, bound into the returned step so its signature is
+    unchanged.  A closed-over array would be embedded in the executable as
+    a constant instead: at roberta-base widths a ~1 GB program that
+    compiles slowly and outgrows the persistent compile cache.
     """
     pred = upload_pred or (lambda p: True)
     axes = None if mesh is None else client_shard_axes(mesh, client_axes)
     agg_fn = factored_fedavg_stacked if factored_agg else fedavg_stacked
 
-    def robust_body(st_trainable, st_opt, pending, batches, train_m, agg_w,
+    def local(b):
+        return local_step_fn if base is None else functools.partial(
+            local_step_fn, b)
+
+    def robust_body(b, st_trainable, st_opt, pending, batches, train_m, agg_w,
                     recv_m, rejoin_m, ontime_m, keys=None):
         # round-input uploaded subtree: the codec's delta reference AND the
         # health scalars' update baseline (send − up_in = this round's delta)
         up_in = (trees.select(st_trainable, pred)
                  if (codec is not None or health) else None)
         ref = up_in if codec is not None else None
+        step_fn = local(b)
 
         def client(tr, op, client_batches):
             def step(carry, batch):
                 tr, op = carry
-                tr, op, loss = local_step_fn(tr, op, batch)
+                tr, op, loss = step_fn(tr, op, batch)
                 return (tr, op), loss
 
             (tr, op), losses = jax.lax.scan(step, (tr, op), client_batches)
@@ -369,18 +385,19 @@ def build_supervised_round(local_step_fn: Callable,
                 axis_names=axes),)
         return outs
 
-    def round_body(st_trainable, st_opt, batches, weights, keys=None):
+    def round_body(b, st_trainable, st_opt, batches, weights, keys=None):
         # server-known reference for delta coding: the round-input value of
         # the uploaded subtree (the previous broadcast global on every
         # non-all-outage round); doubles as the health-delta baseline
         up_in = (trees.select(st_trainable, pred)
                  if (codec is not None or health) else None)
         ref = up_in if codec is not None else None
+        step_fn = local(b)
 
         def client(tr, op, client_batches):
             def step(carry, batch):
                 tr, op = carry
-                tr, op, loss = local_step_fn(tr, op, batch)
+                tr, op, loss = step_fn(tr, op, batch)
                 return (tr, op), loss
 
             (tr, op), losses = jax.lax.scan(step, (tr, op), client_batches)
@@ -440,11 +457,16 @@ def build_supervised_round(local_step_fn: Callable,
             n_in, n_out = n_in + 5, n_out + 1
         # health scalars are psum-ed inside the body → replicated out-spec
         out_specs = (pc,) * n_out + ((P(),) if health else ())
-        round_step = shard_map(body, mesh=mesh,
-                               in_specs=(pc,) * n_in,
-                               out_specs=out_specs, check_vma=False)
+        round_step = jax.shard_map(body, mesh=mesh,
+                                   in_specs=(P(),) + (pc,) * n_in,
+                                   out_specs=out_specs, check_vma=False)
     donate_args = ((0, 1, 2) if robust else (0, 1)) if donate else ()
-    return jax.jit(round_step, donate_argnums=donate_args)
+    if base is None:     # a plain jitted step (``.lower`` for AOT compiles)
+        return jax.jit(functools.partial(round_step, None),
+                       donate_argnums=donate_args)
+    return functools.partial(  # argument 0 is the base, never donated
+        jax.jit(round_step,
+                donate_argnums=tuple(i + 1 for i in donate_args)), base)
 
 
 def build_ppo_round(model, opt, ppo_cfg: PPOConfig, prompt_len: int,
@@ -612,7 +634,7 @@ def build_ppo_round(model, opt, ppo_cfg: PPOConfig, prompt_len: int,
             in_specs = (pc, pc, pr, pc, pc, pc, pc, pc, pc, pc) \
                 + (pc,) * n_extra
             out_specs = (pc, pc, pr, pc, pc) + (pc,) * n_extra
-        body = shard_map(inner, mesh=mesh, in_specs=in_specs,
+        body = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
     def _st_lams(alphas_help):

@@ -435,8 +435,9 @@ def run_pfit(cfg: PFITConfig, mesh=None, client_axes=None) -> Dict:
     tele.start({"mode": "cohort", "method": cfg.method,
                 "n_clients": cfg.n_clients, "rounds": cfg.rounds,
                 "engine": bool(use_engine), "codec": cfg.uplink_codec})
-    profiling = bool(tele_cfg and tele_cfg.jax_profile) and jax_profile_start(
-        os.path.join(tele_cfg.out_dir, "jax_profile"))
+    profiling = bool(tele_cfg and tele_cfg.jax_profile)
+    if profiling:
+        jax_profile_start(os.path.join(tele_cfg.out_dir, "jax_profile"))
 
     for rnd in range(cfg.rounds):
         gains = channel.realize(cfg.n_clients)
@@ -938,8 +939,9 @@ def _run_pfit_population(cfg: PFITConfig, mesh=None, client_axes=None) -> Dict:
     tele.start({"mode": "population", "method": cfg.method,
                 "population": N, "cohort_size": K, "rounds": cfg.rounds,
                 "sampler": pop.sampler, "codec": cfg.uplink_codec})
-    profiling = bool(tele_cfg and tele_cfg.jax_profile) and jax_profile_start(
-        os.path.join(tele_cfg.out_dir, "jax_profile"))
+    profiling = bool(tele_cfg and tele_cfg.jax_profile)
+    if profiling:
+        jax_profile_start(os.path.join(tele_cfg.out_dir, "jax_profile"))
 
     loss_per_round: List[float] = []
     for rnd in range(cfg.rounds):

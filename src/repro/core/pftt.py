@@ -94,7 +94,11 @@ class PFTTConfig:
     local_steps: int = 10
     batch: int = 16
     seq_len: int = 32
-    d_model: int = 128
+    d_model: int = 128             # width of the reduced backbone
+    published_widths: bool = False # backbone at roberta-base's published
+                                   # config (12 layers, d768, 12 heads,
+                                   # vocab 50265) instead of the reduced
+                                   # 2-layer d_model one
     lora_rank: int = 8
     adapter_dim: int = 8
     dirichlet_alpha: float = 0.3
@@ -201,16 +205,19 @@ def _merge_trainable(method: str, base_params, trainable, peft_cfg):
 
 
 def _setup_backbone(cfg: PFTTConfig):
-    """Shared model setup: reduced roberta, MLM pretrain over all topics,
-    PEFT insertion.  Both the cohort path (``run_pftt``) and the population
+    """Shared model setup: roberta (reduced, or at its published widths
+    with ``cfg.published_widths``), MLM pretrain over all topics, PEFT
+    insertion.  Both the cohort path (``run_pftt``) and the population
     path consume it, so their backbones (and the host RNG stream handed
     back) are identical."""
     rng = np.random.RandomState(cfg.seed)
     key = jax.random.PRNGKey(cfg.seed)
     meshctx = MeshCtx.single_device()
 
-    # ---- model: reduced roberta (paper's backbone), pre-trained on IID data
-    mcfg = get_config("roberta-base").reduced(d_model=cfg.d_model, repeats=2)
+    # ---- model: roberta (paper's backbone), pre-trained on IID data
+    mcfg = get_config("roberta-base")
+    if not cfg.published_widths:
+        mcfg = mcfg.reduced(d_model=cfg.d_model, repeats=2)
     model = Model(mcfg, meshctx=meshctx)
     base = model.init(key)
 
@@ -255,6 +262,39 @@ def _setup_backbone(cfg: PFTTConfig):
     return model, mcfg, params, peft_cfg, corpus, key, rng, use_lora
 
 
+def _client_fns(cfg: PFTTConfig, model, opt, peft_cfg):
+    """``(local_step, eval_client)`` of one client, each taking the frozen
+    base as its first argument so the compiled round and eval receive it
+    as an argument (``build_supervised_round(base=)``) instead of
+    embedding ~0.5 GB of constants at published widths.  LoRA runs
+    factored, or merged per ``cfg.factored`` (the oracle)."""
+    scale = peft_mod.lora_scale(peft_cfg)
+
+    def effective(frozen, t):
+        """(params, lora, lora_scale) per the factored/merged flag."""
+        if cfg.factored:
+            full, lora = _split_trainable(cfg.method, frozen, t)
+            return full, lora, scale
+        return _merge_trainable(cfg.method, frozen, t, peft_cfg), None, 1.0
+
+    def local_step(frozen, trainable, opt_state, batch):
+        def loss_fn(t):
+            full, lora, ls = effective(frozen, t)
+            return model.cls_loss(full, batch, lora=lora, lora_scale=ls)[0]
+        loss, g = jax.value_and_grad(loss_fn)(trainable)
+        upd, opt_state = opt.update(g, opt_state, trainable)
+        return trees.tree_add(trainable, upd), opt_state, loss
+
+    def eval_client(frozen, trainable, tokens, label, valid):
+        full, lora, ls = effective(frozen, trainable)
+        hidden, _ = model.forward(full, tokens, lora=lora, lora_scale=ls)
+        pred = (hidden[:, 0] @ full["cls_head"]).astype(jnp.float32).argmax(-1)
+        correct = (pred == label).astype(jnp.float32) * valid
+        return correct.sum(), valid.sum()
+
+    return local_step, eval_client
+
+
 def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
     """``mesh`` (optional ``jax.sharding.Mesh``): shard the fused cohort
     round across it — see the module docstring.  ``client_axes`` overrides
@@ -295,24 +335,9 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
         clients.append({"trainable": t, "opt_state": opt.init(t)})
 
     frozen = params
-    scale = peft_mod.lora_scale(peft_cfg)
-
-    def _effective(t):
-        """(params, lora, lora_scale) per the factored/merged flag."""
-        if cfg.factored:
-            full, lora = _split_trainable(cfg.method, frozen, t)
-            return full, lora, scale
-        return _merge_trainable(cfg.method, frozen, t, peft_cfg), None, 1.0
-
-    def local_step(trainable, opt_state, batch):
-        def loss_fn(t):
-            full, lora, ls = _effective(t)
-            return model.cls_loss(full, batch, lora=lora, lora_scale=ls)[0]
-        loss, g = jax.value_and_grad(loss_fn)(trainable)
-        upd, opt_state = opt.update(g, opt_state, trainable)
-        return trees.tree_add(trainable, upd), opt_state, loss
-
-    local_step_jit = jax.jit(local_step)     # legacy per-client path
+    local_step, eval_client = _client_fns(cfg, model, opt, peft_cfg)
+    # legacy per-client path
+    local_step_jit = functools.partial(jax.jit(local_step), frozen)
 
     # ragged cohorts (unequal client batch sizes) pad-and-mask inside the
     # HostBatchStacker ("valid" sample weights → cls_loss weighted mean), so
@@ -343,15 +368,9 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
         else jnp.asarray
     t_toks, t_labels, t_valid = _put(t_toks), _put(t_labels), _put(t_valid)
 
-    def eval_client(trainable, tokens, label, valid):
-        full, lora, ls = _effective(trainable)
-        hidden, _ = model.forward(full, tokens, lora=lora, lora_scale=ls)
-        pred = (hidden[:, 0] @ full["cls_head"]).astype(jnp.float32).argmax(-1)
-        correct = (pred == label).astype(jnp.float32) * valid
-        return correct.sum(), valid.sum()
-
     eval_cohort = build_cohort_eval(
-        eval_client, sharding=cs.named if cs is not None else None)
+        eval_client, sharding=cs.named if cs is not None else None,
+        base=frozen)
     eval_dispatches = [0]
 
     def eval_round_accs(stacked_trainable):
@@ -368,6 +387,8 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
     ledger = CommLedger()
     upload_pred = _upload_pred(cfg.method)
     accs_per_round = []
+    losses_per_round = []   # mean local-step loss over clients and steps
+                            # (the rounds this process ran)
 
     # ---- observability (repro.obs): JSONL round events + host span tracer
     # (a disabled tracer still times, it just records nothing) + on-device
@@ -406,7 +427,7 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
         uncompressed either way (the codec covers parameter uploads)."""
         if cfg.method != "fedbert":
             return 0.0
-        return cfg.local_steps * cfg.batch * cfg.seq_len * cfg.d_model \
+        return cfg.local_steps * cfg.batch * cfg.seq_len * mcfg.d_model \
             * 4 * 2 * 8
 
     def payload_bytes(trainable) -> int:
@@ -414,14 +435,20 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
         return tree_bytes(shared) + act_bits() / 8
 
     pending = None
+    step_dispatches = [0]
     if use_engine:
-        round_step = build_supervised_round(
+        fused_step = build_supervised_round(
             local_step, upload_pred,
             mesh=cs.mesh if cs is not None else None,
             client_axes=cs.axes if cs is not None else None,
             codec=codec, factored_agg=cfg.factored_agg, robust=robust,
             min_quorum=(dl.min_quorum if dl is not None else 0),
-            health=health)
+            health=health, base=frozen)
+
+        def round_step(*args):
+            step_dispatches[0] += 1
+            return fused_step(*args)
+
         pad = cs.pad if cs is not None else (lambda xs: xs)
         cohort_tr = trees.stack(pad([cl["trainable"] for cl in clients]))
         cohort_opt = trees.stack(pad([cl["opt_state"] for cl in clients]))
@@ -518,8 +545,9 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
         tele.resume(start_round, run_meta)
     else:
         tele.start(run_meta)
-    profiling = bool(tele_cfg and tele_cfg.jax_profile) and jax_profile_start(
-        os.path.join(tele_cfg.out_dir, "jax_profile"))
+    profiling = bool(tele_cfg and tele_cfg.jax_profile)
+    if profiling:
+        jax_profile_start(os.path.join(tele_cfg.out_dir, "jax_profile"))
 
     for rnd in range(start_round, cfg.rounds):
         gains = channel.realize(cfg.n_clients)
@@ -605,8 +633,12 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
                 bits = [float(b) + act_bits()
                         for b in np.asarray(eng_bits)[:cfg.n_clients]]
                 reports = budget.round_reports(bits, gains)
+            step_losses = np.asarray(outs[3] if robust else outs[2])
+            losses_per_round.append(
+                float(step_losses[:cfg.n_clients].mean()))
         else:
             fresh = np.zeros(cfg.n_clients, np.float64)
+            step_losses = np.zeros((cfg.n_clients, cfg.local_steps))
             for ci, cl in enumerate(clients):
                 # every client draws its round batches even when a fault
                 # skips its training — keeps the host data stream aligned
@@ -617,10 +649,11 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
                     continue
                 ref = (trees.select(cl["trainable"], upload_pred)
                        if codec is not None else None)
-                for b_np in round_batches:
+                for si, b_np in enumerate(round_batches):
                     batch = {k: jnp.asarray(v) for k, v in b_np.items()}
                     cl["trainable"], cl["opt_state"], loss = local_step_jit(
                         cl["trainable"], cl["opt_state"], batch)
+                    step_losses[ci, si] = float(loss)
                 if codec is None:
                     fresh[ci] = payload_bytes(cl["trainable"]) * 8
                 else:
@@ -634,6 +667,7 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
             if robust:
                 charged = tracker.end_round(rplan, fresh)
                 reports = _round_reports(rplan, charged, gains)
+            losses_per_round.append(float(step_losses.mean()))
         extra = None
         if dl is not None:
             extra = {"sim_dt_s": float(rplan.sim_dt_s),
@@ -739,6 +773,16 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
         "method": cfg.method,
         "acc_per_round": accs_per_round,
         "final_acc": accs_per_round[-1],
+        "loss_per_round": losses_per_round,
+        "model": {"name": mcfg.name, "n_layers": mcfg.n_layers,
+                  "d_model": mcfg.d_model, "vocab_size": mcfg.vocab_size},
+        # fewest devices any leaf of the engine's stacked client state spans
+        "cohort_state_devices": min(
+            len(l.sharding.device_set)
+            for l in jax.tree_util.tree_leaves(cohort_tr)) if use_engine
+        else None,
+        "dispatches_per_round": (step_dispatches[0]
+                                 / max(cfg.rounds - start_round, 1)),
         "mean_round_bytes": ledger.mean_round_bytes,
         "mean_round_delay_s": ledger.mean_round_delay,
         "total_bytes": ledger.total_bytes,
@@ -826,7 +870,7 @@ def _run_pftt_population(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
     codec = get_codec(cfg.uplink_codec)
     codec_key = None if codec is None else jax.random.fold_in(key, 0x0C0DEC)
     ab = 0.0 if cfg.method != "fedbert" else \
-        cfg.local_steps * cfg.batch * cfg.seq_len * cfg.d_model * 4 * 2 * 8
+        cfg.local_steps * cfg.batch * cfg.seq_len * mcfg.d_model * 4 * 2 * 8
     payload_bits = tree_bytes(shared0) * 8 + ab
     est_bits = None
     if dl is not None:
@@ -836,22 +880,7 @@ def _run_pftt_population(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
 
     # ---- the fused round body: identical to a cohort_size-client robust
     # run (population mode changes NOTHING below the host orchestration)
-    frozen = params
-    scale = peft_mod.lora_scale(peft_cfg)
-
-    def _effective(t):
-        if cfg.factored:
-            full, lora = _split_trainable(cfg.method, frozen, t)
-            return full, lora, scale
-        return _merge_trainable(cfg.method, frozen, t, peft_cfg), None, 1.0
-
-    def local_step(trainable, opt_state, batch):
-        def loss_fn(t):
-            full, lora, ls = _effective(t)
-            return model.cls_loss(full, batch, lora=lora, lora_scale=ls)[0]
-        loss, g = jax.value_and_grad(loss_fn)(trainable)
-        upd, opt_state = opt.update(g, opt_state, trainable)
-        return trees.tree_add(trainable, upd), opt_state, loss
+    local_step, eval_client = _client_fns(cfg, model, opt, peft_cfg)
 
     # ---- observability: the runner owns the spans (its "round" span is
     # the round_s/host_s accounting); health scalars ride the fused body
@@ -867,7 +896,7 @@ def _run_pftt_population(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
         client_axes=cs.axes if cs is not None else None,
         codec=codec, factored_agg=cfg.factored_agg, robust=True,
         min_quorum=(dl.min_quorum if dl is not None else 0),
-        health=health)
+        health=health, base=params)
     stacker = HostBatchStacker(sharding=cs.named if cs is not None else None)
 
     runner = PopulationRunner(
@@ -889,15 +918,9 @@ def _run_pftt_population(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
     _put = (lambda x: jax.device_put(x, cs.named)) if cs is not None \
         else jnp.asarray
 
-    def eval_client(trainable, tokens, label, valid):
-        full, lora, ls = _effective(trainable)
-        hidden, _ = model.forward(full, tokens, lora=lora, lora_scale=ls)
-        pred = (hidden[:, 0] @ full["cls_head"]).astype(jnp.float32).argmax(-1)
-        correct = (pred == label).astype(jnp.float32) * valid
-        return correct.sum(), valid.sum()
-
     eval_cohort = build_cohort_eval(
-        eval_client, sharding=cs.named if cs is not None else None)
+        eval_client, sharding=cs.named if cs is not None else None,
+        base=params)
     test_cache: Dict[int, Dict] = {}
 
     def eval_ids(cohort_tr, ids):
@@ -946,8 +969,9 @@ def _run_pftt_population(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
         tele.resume(start_round, run_meta)
     else:
         tele.start(run_meta)
-    profiling = bool(tele_cfg and tele_cfg.jax_profile) and jax_profile_start(
-        os.path.join(tele_cfg.out_dir, "jax_profile"))
+    profiling = bool(tele_cfg and tele_cfg.jax_profile)
+    if profiling:
+        jax_profile_start(os.path.join(tele_cfg.out_dir, "jax_profile"))
 
     for rnd in range(start_round, cfg.rounds):
         out = runner.run_round(rnd, round_step=round_step, stacker=stacker,

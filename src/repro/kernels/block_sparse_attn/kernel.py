@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -63,7 +65,7 @@ def _kernel(idx_ref, valid_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def block_sparse_attention_kernel(q, k, v, idx, valid, *, block: int,
-                                  interpret: bool = True):
+                                  interpret: bool | None = None):
     """q: (BH, Sq, d); k/v: (BK, Sk, d); idx/valid: (n_q_blocks, A) static
     tables from ``sparse_block_table``.  Returns (BH, Sq, d)."""
     bh, sq, d = q.shape
@@ -103,5 +105,5 @@ def block_sparse_attention_kernel(q, k, v, idx, valid, *, block: int,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(idx, valid, q, k, v)

@@ -14,7 +14,7 @@ from repro.models.attention import sparse_block_table
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
 def block_sparse_attention(q, k, v, cfg: SparseAttnConfig, *,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: (B, Sq, H, hd); k/v: (B, Sk, K, hd) → (B, Sq, H, hd)."""
     b, sq, h, d = q.shape
     _, sk, kh, _ = k.shape

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -59,7 +61,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def decode_attention_kernel(q, k_cache, v_cache, pos, *, window: int = 0,
-                            bk: int = 128, interpret: bool = True):
+                            bk: int = 128, interpret: bool | None = None):
     """q: (BH, 1, d); caches: (BK, Sc, d) with BH = BK·group; pos: scalar
     int32 (cache_len − 1).  Returns (BH, 1, d)."""
     bh, _, d = q.shape
@@ -93,5 +95,5 @@ def decode_attention_kernel(q, k_cache, v_cache, pos, *, window: int = 0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(jnp.asarray(pos, jnp.int32)[None], q, k_cache, v_cache)

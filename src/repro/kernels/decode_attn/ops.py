@@ -10,7 +10,7 @@ from repro.kernels.decode_attn.kernel import decode_attention_kernel
 
 @functools.partial(jax.jit, static_argnames=("window", "bk", "interpret"))
 def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
-                     bk: int = 128, interpret: bool = True):
+                     bk: int = 128, interpret: bool | None = None):
     """q: (B, 1, H, hd); caches: (B, Sc, K, hd); pos scalar → (B, 1, H, hd)."""
     b, _, h, d = q.shape
     _, sc, kh, _ = k_cache.shape

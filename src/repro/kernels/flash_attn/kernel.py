@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -66,7 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: (BH, Sq, d); k/v: (BK, Sk, d) with BH = BK·group.
     Returns (BH, Sq, d)."""
     bh, sq, d = q.shape
@@ -97,5 +99,5 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

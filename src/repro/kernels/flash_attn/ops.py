@@ -13,7 +13,7 @@ from repro.kernels.flash_attn.kernel import flash_attention_kernel
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+                    bq: int = 128, bk: int = 128, interpret: bool | None = None):
     """q: (B, Sq, H, hd); k/v: (B, Sk, K, hd) → (B, Sq, H, hd).
 
     Flattens (batch, head) into the kernel's leading grid dim; GQA sharing is

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, xa_ref, *,
             scale: float, n_k: int):
@@ -42,27 +44,29 @@ def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, xa_ref, *,
         o_ref[...] = (acc_ref[...] + scale * lora).astype(o_ref.dtype)
 
 
-def _block(dim: int, pref: int) -> int:
-    """Largest usable block ≤ pref that tiles ``dim`` exactly; falls back to
-    the whole dim (fine in interpret mode / small models) so the kernel
-    accepts the model's real projection shapes, not only 128-multiples."""
+def _block(dim: int, pref: int, align: int) -> int:
+    """Block size along one dim: the whole dim when it fits in ``pref``,
+    else the largest multiple of ``align`` (the TPU tiling: 8 f32 / 16 bf16
+    rows, or 128 lanes) ≤ ``pref`` that tiles ``dim`` exactly.  Any other block is
+    refused by the chip's compiler, so a dim with none raises."""
     if dim <= pref:
         return dim
-    if dim % pref == 0:
-        return pref
-    for cand in range(pref, 0, -1):
+    for cand in range(pref - pref % align, 0, -align):
         if dim % cand == 0:
             return cand
-    return dim
+    raise ValueError(f"lora_fused: dim {dim} > {pref} has no block that is "
+                     f"a multiple of {align} and divides it")
 
 
 def lora_fused_kernel(x, w, a, b, *, scale: float, bm: int = 128,
-                      bn: int = 128, bk: int = 128, interpret: bool = True):
+                      bn: int = 128, bk: int = 128,
+                      interpret: bool | None = None):
     """x: (M, K); w: (K, N); a: (K, r); b: (r, N) → (M, N)."""
     m, k = x.shape
     _, n = w.shape
     r = a.shape[1]
-    bm, bn, bk = _block(m, bm), _block(n, bn), _block(k, bk)
+    rows = max(8, 32 // x.dtype.itemsize)
+    bm, bn, bk = _block(m, bm, rows), _block(n, bn, 128), _block(k, bk, 128)
     nm, nn, nk = m // bm, n // bn, k // bk
 
     kernel = functools.partial(_kernel, scale=scale, n_k=nk)
@@ -81,5 +85,5 @@ def lora_fused_kernel(x, w, a, b, *, scale: float, bm: int = 128,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, w, a, b)

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hlast_ref, state_ref,
             *, chunk: int, n_chunks: int):
@@ -63,7 +65,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hlast_ref, state_ref,
 
 
 def ssd_chunk_kernel(x, dt, a_coef, bmat, cmat, *, chunk: int,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """x: (BH, S, P); dt: (BH, S); a_coef: (BH,); b/c: (BH, S, N)
     → (y (BH, S, P), h_final (BH, P, N))."""
     bh, s, p = x.shape
@@ -92,6 +94,6 @@ def ssd_chunk_kernel(x, dt, a_coef, bmat, cmat, *, chunk: int,
             jax.ShapeDtypeStruct((bh, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt[..., None], a_coef[:, None], bmat, cmat)
     return y, hlast

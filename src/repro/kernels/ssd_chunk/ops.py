@@ -11,7 +11,7 @@ from repro.kernels.ssd_chunk.kernel import ssd_chunk_kernel
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, a_coef, bmat, cmat, *, chunk: int = 256,
-             interpret: bool = True):
+             interpret: bool | None = None):
     """Model layout: x (B,S,H,P); dt (B,S,H); a_coef (H,); b/c (B,S,H,N)
     → (y (B,S,H,P), h_final (B,H,P,N))."""
     b, s, h, p = x.shape

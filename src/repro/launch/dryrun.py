@@ -29,14 +29,14 @@ import numpy as np
 
 from repro import trees
 from repro.configs import ASSIGNED, SHAPES, get_config
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_meshctx)
+from repro.launch.mesh import (PRODUCTION_DEVICE_KIND, device_peaks,
+                               make_meshctx)
 from repro.launch.steps import (make_fl_round_step, make_input_batch_shapes,
                                 make_peft_step, make_prefill_step,
                                 make_serve_step, make_train_step)
 from repro.models import Model
 from repro.models import peft as peft_mod
-from repro.sharding import (batch_specs, cache_specs, param_specs,
-                            use_mesh, with_specs)
+from repro.sharding import batch_specs, cache_specs, param_specs, with_specs
 
 COLLECTIVE_RE = re.compile(
     r"(\w+\[[^\]]*\](?:\s*,\s*\w+\[[^\]]*\])*)\s*"
@@ -181,7 +181,7 @@ def lower_one(arch: str, shape_name: str, mesh_kind: str, step: str = "auto",
         opt_in = {"mu": with_specs(opt_shapes["mu"], ospecs, mesh),
                   "nu": with_specs(opt_shapes["nu"], ospecs, mesh),
                   "step": jax.ShapeDtypeStruct((), jnp.int32)}
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn).lower(params_in, opt_in, batch_in)
         lower_args = (step_fn, (params_in, opt_in, batch_in), 0)
     elif step == "train_peft":
@@ -204,13 +204,13 @@ def lower_one(arch: str, shape_name: str, mesh_kind: str, step: str = "auto",
         opt_in = {"mu": with_specs(opt_shapes["mu"], tspecs, mesh),
                   "nu": with_specs(opt_shapes["nu"], tspecs, mesh),
                   "step": jax.ShapeDtypeStruct((), jnp.int32)}
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn).lower(trainable_in, frozen_in, opt_in,
                                              batch_in)
         lower_args = (step_fn, (trainable_in, frozen_in, opt_in, batch_in), 0)
     elif step == "prefill":
         step_fn = make_prefill_step(model, cache_len=shape.seq_len, impl=impl)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn).lower(params_in, batch_in)
         cache_b = sds_tree_bytes(model.cache_spec(shape.global_batch,
                                                   shape.seq_len))
@@ -225,7 +225,7 @@ def lower_one(arch: str, shape_name: str, mesh_kind: str, step: str = "auto",
             jax.ShapeDtypeStruct((shape.global_batch, 1), jnp.int32),
             batch_specs(meshctx, jax.ShapeDtypeStruct(
                 (shape.global_batch, 1), jnp.int32)), mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn).lower(params_in, cache_in, tok_in)
         lower_args = (step_fn, (params_in, cache_in, tok_in),
                       sds_tree_bytes(cache_shapes))
@@ -264,7 +264,7 @@ def lower_one(arch: str, shape_name: str, mesh_kind: str, step: str = "auto",
         opt_in = {"mu": with_specs(opt_shapes["mu"], tspecs, mesh),
                   "nu": with_specs(opt_shapes["nu"], tspecs, mesh),
                   "step": jax.ShapeDtypeStruct((), jnp.int32)}
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn).lower(trainable_in, frozen_in, opt_in,
                                              batch_in)
         lower_args = (step_fn, (trainable_in, frozen_in, opt_in, batch_in), 0)
@@ -317,9 +317,12 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, step: str = "auto",
         a = sp.sink_blocks + sp.local_blocks + max(1, nb // sp.stride)
         eff_cache = int(cache_bytes * min(1.0, a / nb))
     mem_global = analytic_memory_bytes(cfg, shape, step, eff_cache)
-    compute_s = global_flops / n_chips / PEAK_FLOPS_BF16
-    memory_s = mem_global / n_chips / HBM_BW
-    collective_s = coll_wire / ICI_BW   # HLO is already per-device
+    # analytic roofline of the production target, not of the host that runs
+    # this compile
+    peaks = device_peaks(PRODUCTION_DEVICE_KIND)
+    compute_s = global_flops / n_chips / peaks.flops_bf16
+    memory_s = mem_global / n_chips / peaks.hbm_bytes_per_s
+    collective_s = coll_wire / peaks.ici_bytes_per_s  # HLO is per-device
 
     n_active = cfg.active_param_count()
     tokens = shape.global_batch * (shape.seq_len

@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Production mesh construction and the device peak table.
 
 Target: TPU v5e, 256 chips/pod.  Single-pod mesh is (16 data × 16 model);
 multi-pod adds a leading pod axis (2 × 16 × 16 = 512 chips).  Defined as
@@ -8,9 +8,11 @@ TPU launcher should call these.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 
-from repro.sharding import MeshCtx
+from repro.sharding import MeshCtx, auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,9 +24,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     devices = jax.devices()[:n]
     if len(devices) < n:
         raise RuntimeError(
-            f"need {n} devices for mesh {shape}, have {len(devices)} — "
-            "did you set --xla_force_host_platform_device_count?")
-    return jax.make_mesh(shape, axes, devices=devices)
+            f"need {n} devices for mesh {shape}, have {len(devices)} "
+            f"({devices[0].device_kind if devices else 'none'})")
+    return auto_mesh(shape, axes, devices=devices)
 
 
 def make_meshctx(*, multi_pod: bool = False) -> MeshCtx:
@@ -33,7 +35,31 @@ def make_meshctx(*, multi_pod: bool = False) -> MeshCtx:
     return MeshCtx(mesh=mesh, batch_axes=batch_axes, model_axis="model")
 
 
-# Hardware constants for the roofline model (TPU v5e)
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    flops_bf16: float          # FLOP/s
+    hbm_bytes_per_s: float     # bytes/s
+    ici_bytes_per_s: float     # chip-to-chip interconnect, bytes/s per chip
+
+
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops_bf16=197e12, hbm_bytes_per_s=819e9,
+                               ici_bytes_per_s=1600e9 / 8),
+}
+
+# The chip the production meshes above are laid out for.
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; a kind without a published entry is an
+    error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(DEVICE_PEAKS)}") from None

@@ -7,8 +7,9 @@ TPU pod in production — the same pjit program the dry-run compiles).
 ``--fl-clients N`` instead runs the federated cohort engine with the
 stacked client axis sharded over every available device (``shard_map``
 round, psum aggregation — core/cohort.py).  ``--arch roberta-base`` runs
-PFTT's reduced-roberta classification cohort (``--steps``/``--seq`` don't
-apply; ``--batch``/``--lr``/``--fl-rounds`` do):
+PFTT's roberta classification cohort, reduced unless
+``--published-widths`` (``--steps``/``--seq`` don't apply;
+``--batch``/``--lr``/``--fl-rounds`` do):
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
         python -m repro.launch.train --arch roberta-base --fl-clients 8 \
@@ -43,10 +44,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, list_configs
 from repro.launch.steps import make_train_step
 from repro.models import Model
-from repro.sharding import MeshCtx, batch_specs, param_specs, use_mesh
+from repro.sharding import MeshCtx, auto_mesh, param_specs
 
 
 def main():
@@ -144,9 +146,15 @@ def main():
                     help="arch FL round: per-sample sequence length")
     ap.add_argument("--fl-dmodel", type=int, default=64,
                     help="arch FL round: reduced-config width")
+    ap.add_argument("--published-widths", action="store_true",
+                    help="FL runs: build the model from the architecture's "
+                         "published config (roberta-base for PFTT: 12 "
+                         "layers, d768, vocab 50265) instead of the reduced "
+                         "one; --fl-dmodel is then ignored")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     n_dev = len(jax.devices())
     if args.population and args.arch != "roberta-base":
         raise SystemExit("--population runs the PFTT workload: "
@@ -155,11 +163,12 @@ def main():
         from repro.core.arch_round import ArchRoundConfig, run_arch_round
         print(f"universal fused round: --arch {args.arch}, "
               f"{args.fl_clients} clients on {n_dev} device(s)")
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        mesh = auto_mesh((n_dev,), ("data",))
         cfg = ArchRoundConfig(arch=args.arch, n_clients=args.fl_clients,
                               rounds=args.fl_rounds,
                               batch=min(args.batch, 4), seq_len=args.fl_seq,
                               d_model=args.fl_dmodel, lr=args.lr,
+                              published_widths=args.published_widths,
                               oracle=args.assert_fused)
         res = run_arch_round(cfg, mesh=mesh, client_axes=("data",))
         print(f"arch={res['arch']} targets={res['lora_targets']} "
@@ -210,12 +219,14 @@ def main():
                   f"cohort {args.cohort}/round ({args.sampler} sampling) "
                   f"on {n_dev} device(s)")
         else:
-            print(f"federated cohort demo (PFTT reduced-roberta workload; "
-                  f"--steps/--seq ignored) on {n_dev} device(s)")
-        mesh = jax.make_mesh((n_dev,), ("data",))
+            print(f"federated cohort demo (PFTT roberta workload, "
+                  f"{'published' if args.published_widths else 'reduced'} "
+                  f"widths; --steps/--seq ignored) on {n_dev} device(s)")
+        mesh = auto_mesh((n_dev,), ("data",))
         cfg = PFTTConfig(n_clients=args.fl_clients or args.cohort,
                          rounds=args.fl_rounds,
                          batch=args.batch, lr=args.lr, local_steps=5,
+                         published_widths=args.published_widths,
                          pretrain_steps=50, samples_per_client=200,
                          uplink_codec=args.uplink_codec,
                          factored_agg=args.factored_agg,
@@ -246,7 +257,7 @@ def main():
             print("fused path asserted: engine round")
         return
     d = args.data_axis or n_dev
-    mesh = jax.make_mesh((d, n_dev // d), ("data", "model"))
+    mesh = auto_mesh((d, n_dev // d), ("data", "model"))
     meshctx = MeshCtx(mesh=mesh)
     cfg = get_config(args.arch)
     if args.reduced:
@@ -265,7 +276,7 @@ def main():
     rng = np.random.RandomState(0)
     jstep = jax.jit(step_fn)
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(args.steps):
             toks = jnp.asarray(rng.randint(6, cfg.vocab_size,
                                            size=(args.batch, args.seq + 1)))

@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
 from repro.models.mlp import act_fn
-from repro.sharding import MeshCtx, shard_map
+from repro.sharding import MeshCtx
 
 
 def _capacity(n_tokens: int, cfg: MoEConfig) -> int:
@@ -121,13 +121,20 @@ def moe_ffn(x, params, cfg: MoEConfig, meshctx: MeshCtx, act: str):
         _local_moe, cfg=cfg, act=act, e_loc=e_loc,
         model_axis=meshctx.model_axis, shard_experts=shard_experts,
         batch_axes=aux_axes, psum_axes=psum_axes)
+    args = (x, params["router"], params["wg"], params["wu"], params["wd"])
 
-    y, aux = shard_map(
-        body, mesh=meshctx.mesh,
-        in_specs=(bspec, P(None, None), gu_spec, gu_spec, d_spec),
-        out_specs=(bspec, P()),
-        check_vma=False,
-    )(x, params["router"], params["wg"], params["wu"], params["wd"])
+    if not psum_axes and not aux_axes:
+        # every operand is replicated, so the body is plain local compute.
+        # Run it directly: inside the cohort round's shard_map a nested
+        # shard_map over the model's own mesh is refused.
+        y, aux = body(*args)
+    else:
+        y, aux = jax.shard_map(
+            body, mesh=meshctx.mesh,
+            in_specs=(bspec, P(None, None), gu_spec, gu_spec, d_spec),
+            out_specs=(bspec, P()),
+            check_vma=False,
+        )(*args)
 
     # shared (always-on) experts — a plain dense FF of width n_shared·f
     if cfg.n_shared_experts > 0:
@@ -263,7 +270,7 @@ def moe_ffn_a2a(x, params, cfg: MoEConfig, meshctx: MeshCtx, act: str):
     body = functools.partial(
         _local_moe_a2a, cfg=cfg, act=act, e_loc=e_loc,
         model_axis=meshctx.model_axis, n_model=msize, axes=aux_axes)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=meshctx.mesh,
         in_specs=(bspec, P(None, None), expert_spec, expert_spec, expert_spec),
         out_specs=(bspec, P()),

@@ -196,7 +196,11 @@ def lora_proj(x, w, lf, *, scale: float, backend: str = "jnp"):
     # layer scan has sliced the (repeats, 1, 1) leaf, broadcasting over
     # (r, dout) — identical math to masking the dense delta
     b = b * mask.astype(b.dtype)
-    if backend == "pallas" and w.ndim == 2 and x.ndim >= 2:
+    if backend == "pallas":
+        if w.ndim != 2 or a.ndim != 2 or x.ndim < 2:
+            raise ValueError(
+                f"lora_proj(backend='pallas') takes 2-D unstacked weights and "
+                f"x of rank >= 2; got x {x.shape}, w {w.shape}, a {a.shape}")
         from repro.kernels.lora_fused.ops import lora_matmul
         return lora_matmul(x, w, a, b, scale=scale)
     return x @ w + scale * ((x @ a) @ b)

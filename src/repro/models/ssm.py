@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from repro.configs.base import SSMConfig
 from repro.models.norms import rmsnorm
 from repro.models.peft import lora_proj
-from repro.sharding import shard_map
 
 
 def _lf(lora, key):
@@ -325,7 +324,7 @@ def mamba_seq_sp(x, p, cfg: SSMConfig, d_model: int, eps: float, meshctx):
     body = functools.partial(_sp_body, cfg=cfg, d_model=d_model, eps=eps,
                              model_axis=meshctx.model_axis, n_dev=msize)
     rep = P(None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=meshctx.mesh,
         in_specs=(bspec, rep, rep, P(None), P(None), P(None), P(None),
                   P(None), rep),

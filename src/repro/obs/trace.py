@@ -29,9 +29,8 @@ Span-name convention (used by every runner; see docs/observability.md):
 https://ui.perfetto.dev (or chrome://tracing) directly.
 
 ``jax_profile_start``/``jax_profile_stop`` bracket the run with
-``jax.profiler`` for device-side traces (TensorBoard/Perfetto); they are
-best-effort — a backend without profiler support degrades to a no-op
-instead of failing the run.
+``jax.profiler`` for device-side traces (TensorBoard/Perfetto); a
+profiler that fails to start or stop raises.
 """
 from __future__ import annotations
 
@@ -117,21 +116,15 @@ class SpanTracer:
 # ---------------------------------------------------------------------------
 
 
-def jax_profile_start(out_dir: str) -> bool:
-    """Best-effort ``jax.profiler.start_trace``; False when the backend
-    has no profiler (the run continues without device traces)."""
-    try:
-        import jax
-        os.makedirs(out_dir, exist_ok=True)
-        jax.profiler.start_trace(out_dir)
-        return True
-    except Exception:
-        return False
+def jax_profile_start(out_dir: str) -> None:
+    """``jax.profiler.start_trace`` into ``out_dir``.  A profiler that
+    cannot start raises: a run asked to trace the device never carries on
+    without the trace."""
+    import jax
+    os.makedirs(out_dir, exist_ok=True)
+    jax.profiler.start_trace(out_dir)
 
 
 def jax_profile_stop() -> None:
-    try:
-        import jax
-        jax.profiler.stop_trace()
-    except Exception:
-        pass
+    import jax
+    jax.profiler.stop_trace()

@@ -15,30 +15,18 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """``jax.shard_map`` compat shim: on jax ≥ 0.6 forwards directly; on
-    0.4.x (this container) routes to ``jax.experimental.shard_map`` with
-    ``check_vma`` mapped to its older ``check_rep`` spelling.  Model code
-    must use THIS instead of ``jax.shard_map``."""
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-def use_mesh(mesh: Mesh):
-    """``jax.set_mesh`` compat: a context manager activating ``mesh`` (on
-    0.4.x the Mesh object itself is the context manager)."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+def auto_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """The one mesh constructor: every axis ``Auto``, so sharding is left to
+    the partitioner and plain jnp indexing (embedding gathers, cache
+    writes, ``trees.unstack``) needs no per-op sharding annotations.
+    ``devices`` defaults to the first ``prod(shape)`` of ``jax.devices()``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 # ---------------------------------------------------------------------------
@@ -142,8 +130,7 @@ class MeshCtx:
 
     @classmethod
     def single_device(cls) -> "MeshCtx":
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
-        return cls(mesh=mesh)
+        return cls(mesh=auto_mesh((1, 1), ("data", "model")))
 
     # -- divisibility-aware spec construction --------------------------------
     def dim_axis(self, size: int, axis) -> Optional[object]:

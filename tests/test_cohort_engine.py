@@ -176,6 +176,9 @@ def test_pftt_engine_matches_loop():
     fused = run_pftt(PFTTConfig(engine=True, **kw))
     np.testing.assert_allclose(legacy["acc_per_round"],
                                fused["acc_per_round"], atol=1e-5)
+    np.testing.assert_allclose(legacy["loss_per_round"],
+                               fused["loss_per_round"], rtol=1e-5)
+    assert fused["dispatches_per_round"] == 1.0
     assert legacy["mean_round_bytes"] == fused["mean_round_bytes"]
     assert legacy["mean_round_delay_s"] == fused["mean_round_delay_s"]
 

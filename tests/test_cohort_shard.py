@@ -25,8 +25,8 @@ from repro import trees
 from repro.core.aggregation import masked_fedavg_stacked
 from repro.core.cohort import build_supervised_round
 from repro.optim import sgd
-from repro.sharding import (CohortSharding, client_shard_axes,
-                            cohort_sharding, shard_map)
+from repro.sharding import (CohortSharding, auto_mesh, client_shard_axes,
+                            cohort_sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -35,11 +35,11 @@ from repro.sharding import (CohortSharding, client_shard_axes,
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("pod", "data"))
+    return auto_mesh((1, 1), ("pod", "data"))
 
 
 def test_client_shard_axes_excludes_model():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     assert client_shard_axes(mesh) == ("data",)
     assert client_shard_axes(_mesh11()) == ("pod", "data")
     assert client_shard_axes(_mesh11(), ("data",)) == ("data",)
@@ -138,7 +138,7 @@ def test_masked_fedavg_axis_names_matches_plain():
     def agg(g, t, m, w):
         return masked_fedavg_stacked(g, t, m, w, axis_names=axes)
 
-    f = shard_map(agg, mesh=mesh,
+    f = jax.shard_map(agg, mesh=mesh,
                   in_specs=(P(), P(axes), P(axes), P(axes)),
                   out_specs=P(), check_vma=False)
     for w in ([1.0, 0.0, 1.0, 0.5, 0.0], [0.0] * 5):
@@ -158,7 +158,8 @@ _PREAMBLE = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     import numpy as np
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    from repro.sharding import auto_mesh
+    mesh = auto_mesh((2, 4), ("pod", "data"))
     assert len(jax.devices()) == 8
 """)
 

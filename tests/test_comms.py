@@ -22,6 +22,7 @@ from repro.comms.factored_agg import factored_fedavg_tree
 from repro.core.aggregation import fedavg_stacked
 from repro.core.cohort import build_supervised_round
 from repro.optim import sgd
+from repro.sharding import auto_mesh
 from repro.wireless import CommLedger, RayleighChannel, tree_bytes
 from repro.wireless.channel import ChannelReport
 
@@ -322,7 +323,7 @@ def test_codec_round_sharded_one_device_mesh_matches_unsharded():
     == the unsharded fused round — the collective math (psum + factor
     all-gather) collapses to the single-device math."""
     codec = get_codec("int8")
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = auto_mesh((1, 1), ("pod", "data"))
     plain, st_tr, st_op, batches, keys = _toy_codec_round(codec)
     sharded, *_ = _toy_codec_round(codec, mesh=mesh)
     w = jnp.asarray([1.0, 0.0, 1.0])

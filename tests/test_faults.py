@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from repro.core.robust import RoundPlan, StalenessConfig, StalenessTracker
+from repro.sharding import auto_mesh
 from repro.wireless.faults import FaultPlan, FaultTrace, RoundFaults
 
 
@@ -286,7 +287,7 @@ def test_pftt_fault_sharded_one_device_matches_unsharded():
     unsharded engine — fault masks and agg weights ride the client axis."""
     from repro.core.pftt import PFTTConfig, run_pftt
     plain = run_pftt(PFTTConfig(engine=True, **PFTT_KW, **ROBUST_KW))
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = auto_mesh((1, 1), ("pod", "data"))
     sharded = run_pftt(PFTTConfig(engine=True, **PFTT_KW, **ROBUST_KW),
                        mesh=mesh, client_axes=("pod", "data"))
     np.testing.assert_allclose(plain["acc_per_round"],

@@ -19,7 +19,7 @@ from repro import trees
 from repro.configs import get_config
 from repro.models import Model
 from repro.models import peft as peft_mod
-from repro.sharding import MeshCtx
+from repro.sharding import MeshCtx, auto_mesh
 
 KEY = jax.random.PRNGKey(0)
 
@@ -193,7 +193,7 @@ def test_client_vmap_parity(arch, targets):
 @pytest.mark.parametrize("arch", ["deepseek-v2-236b", "mamba2-1.3b"])
 def test_arch_round_one_device_mesh_matches_oracle(arch):
     from repro.core.arch_round import ArchRoundConfig, run_arch_round
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     res = run_arch_round(
         ArchRoundConfig(arch=arch, n_clients=2, rounds=1, local_steps=2,
                         batch=3, seq_len=12, d_model=32, oracle=True),

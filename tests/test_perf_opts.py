@@ -105,24 +105,24 @@ def test_distributed_opts_match_8dev():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.sharding import MeshCtx, use_mesh
+        from repro.sharding import MeshCtx, auto_mesh
         from repro.models.moe import init_moe, moe_ffn, moe_ffn_a2a
         from repro.models.ssm import init_mamba, mamba_seq, mamba_seq_sp
         from repro.configs.base import MoEConfig, SSMConfig
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         mc = MeshCtx(mesh=mesh, batch_axes=("data",))
         key = jax.random.PRNGKey(0)
         cfg = MoEConfig(n_experts=8, top_k=2, d_ff=64, capacity_factor=4.0)
         p = init_moe(key, 32, cfg, "swiglu", jnp.float32)
         x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, 32))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             y1, _ = jax.jit(lambda x: moe_ffn(x, p, cfg, mc, "swiglu"))(x)
             y2, _ = jax.jit(lambda x: moe_ffn_a2a(x, p, cfg, mc, "swiglu"))(x)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
         scfg = SSMConfig(state=16, headdim=8, expand=2, chunk=8, conv_width=4)
         pm = init_mamba(key, 32, scfg, jnp.float32)
         xm = jax.random.normal(jax.random.fold_in(key, 2), (4, 64, 32))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_sp = jax.jit(lambda x: mamba_seq_sp(x, pm, scfg, 32, 1e-5, mc))(xm)
         y_ref, _ = mamba_seq(xm, pm, scfg, 32, 1e-5)
         np.testing.assert_allclose(np.asarray(y_sp), np.asarray(y_ref),

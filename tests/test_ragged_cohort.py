@@ -137,7 +137,8 @@ RAGGED_SHARD_SUBPROC = textwrap.dedent("""
     import jax
     import numpy as np
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.sharding import auto_mesh
+    mesh = auto_mesh((8,), ("data",))
     from repro.core.arch_round import ArchRoundConfig, run_arch_round
     # 3 ragged clients over 8 shards → 5 zero-weight ghosts
     cfg = ArchRoundConfig(arch="gpt2-small", n_clients=3, rounds=2,

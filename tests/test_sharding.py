@@ -35,11 +35,11 @@ SUBPROC = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.configs import get_config
     from repro.models import Model
-    from repro.sharding import (MeshCtx, batch_specs, param_specs,
-                                use_mesh, with_specs)
+    from repro.sharding import (MeshCtx, auto_mesh, batch_specs, param_specs,
+                                with_specs)
     from repro import trees
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     mc = MeshCtx(mesh=mesh, batch_axes=("data",))
     cfg = get_config("{arch}").reduced(d_model=256, repeats=2)
     model = Model(cfg, meshctx=mc)
@@ -61,7 +61,7 @@ SUBPROC = textwrap.dedent("""
     def loss_fn(p, b):
         return model.lm_loss(p, b)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         l = loss_fn(params, batch)
     assert np.isfinite(float(l)), l
     # sharded value == single-device value
