@@ -487,27 +487,28 @@ def run_pfit(cfg: PFITConfig, mesh=None, client_axes=None) -> Dict:
                          for ci in range(cfg.n_clients)]))
                 if robust and codec is None:
                     with tracer.span("device-step"):
-                        outs = round_step(
-                            cohort_tr, cohort_opt, pending, batches, *margs)
+                        outs = jax.block_until_ready(round_step(
+                            cohort_tr, cohort_opt, pending, batches, *margs))
                     cohort_tr, cohort_opt, pending = outs[:3]
                     bits = [payloads[ci] * 8 for ci in range(cfg.n_clients)]
                 elif robust:
                     with tracer.span("device-step"):
-                        outs = round_step(cohort_tr, cohort_opt, pending,
-                                          batches, *margs, ck)
+                        outs = jax.block_until_ready(round_step(
+                            cohort_tr, cohort_opt, pending, batches, *margs,
+                            ck))
                     cohort_tr, cohort_opt, pending = outs[:3]
                     bits = [float(b)
                             for b in np.asarray(outs[4])[:cfg.n_clients]]
                 elif codec is None:
                     with tracer.span("device-step"):
-                        outs = round_step(
-                            cohort_tr, cohort_opt, batches, weights)
+                        outs = jax.block_until_ready(round_step(
+                            cohort_tr, cohort_opt, batches, weights))
                     cohort_tr, cohort_opt = outs[:2]
                     bits = [payloads[ci] * 8 for ci in range(cfg.n_clients)]
                 else:
                     with tracer.span("device-step"):
-                        outs = round_step(
-                            cohort_tr, cohort_opt, batches, weights, ck)
+                        outs = jax.block_until_ready(round_step(
+                            cohort_tr, cohort_opt, batches, weights, ck))
                     cohort_tr, cohort_opt = outs[:2]
                     bits = [float(b)
                             for b in np.asarray(outs[3])[:cfg.n_clients]]
@@ -529,40 +530,37 @@ def run_pfit(cfg: PFITConfig, mesh=None, client_axes=None) -> Dict:
                 if robust and codec is None:
                     with tracer.span("device-step"):
                         (cohort_tr, cohort_opt, global_params, pending, _,
-                         _) = ppo_round_step(cohort_tr, cohort_opt,
-                                             global_params, pending, st_masks,
-                                             prompts, keys, alphas_h,
-                                             alphas_s, weights,
-                                             _vec(rplan.train, 1.0),
-                                             _vec(rplan.recv, 1.0),
-                                             _vec(rplan.rejoin, 0.0),
-                                             _vec(ontime, 1.0))
+                         _) = jax.block_until_ready(ppo_round_step(
+                             cohort_tr, cohort_opt, global_params, pending,
+                             st_masks, prompts, keys, alphas_h, alphas_s,
+                             weights, _vec(rplan.train, 1.0),
+                             _vec(rplan.recv, 1.0), _vec(rplan.rejoin, 0.0),
+                             _vec(ontime, 1.0)))
                     bits = [payloads[ci] * 8 for ci in range(cfg.n_clients)]
                 elif robust:
                     with tracer.span("device-step"):
                         (cohort_tr, cohort_opt, global_params, pending, _, _,
-                         eng_bits) = ppo_round_step(
+                         eng_bits) = jax.block_until_ready(ppo_round_step(
                             cohort_tr, cohort_opt, global_params, pending,
                             st_masks, prompts, keys, alphas_h, alphas_s,
                             weights, _vec(rplan.train, 1.0),
                             _vec(rplan.recv, 1.0), _vec(rplan.rejoin, 0.0),
-                            _vec(ontime, 1.0), ck)
+                            _vec(ontime, 1.0), ck))
                     bits = [float(b)
                             for b in np.asarray(eng_bits)[:cfg.n_clients]]
                 elif codec is None:
                     with tracer.span("device-step"):
                         (cohort_tr, cohort_opt, global_params, _,
-                         _) = ppo_round_step(cohort_tr, cohort_opt,
-                                             global_params, st_masks, prompts,
-                                             keys, alphas_h, alphas_s,
-                                             weights)
+                         _) = jax.block_until_ready(ppo_round_step(
+                             cohort_tr, cohort_opt, global_params, st_masks,
+                             prompts, keys, alphas_h, alphas_s, weights))
                     bits = [payloads[ci] * 8 for ci in range(cfg.n_clients)]
                 else:
                     with tracer.span("device-step"):
                         (cohort_tr, cohort_opt, global_params, _, _,
-                         eng_bits) = ppo_round_step(
+                         eng_bits) = jax.block_until_ready(ppo_round_step(
                             cohort_tr, cohort_opt, global_params, st_masks,
-                            prompts, keys, alphas_h, alphas_s, weights, ck)
+                            prompts, keys, alphas_h, alphas_s, weights, ck))
                     bits = [float(b)
                             for b in np.asarray(eng_bits)[:cfg.n_clients]]
                 for cl, p in zip(clients,

@@ -597,15 +597,16 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
                          _vec(ontime, 1.0))
                 if codec is None:
                     with tracer.span("device-step"):
-                        outs = round_step(
-                            cohort_tr, cohort_opt, pending, batches, *margs)
+                        outs = jax.block_until_ready(round_step(
+                            cohort_tr, cohort_opt, pending, batches, *margs))
                     cohort_tr, cohort_opt, pending = outs[:3]
                     fresh = np.asarray([payloads[ci] * 8
                                         for ci in range(cfg.n_clients)])
                 else:
                     with tracer.span("device-step"):
-                        outs = round_step(cohort_tr, cohort_opt, pending,
-                                          batches, *margs, ck)
+                        outs = jax.block_until_ready(round_step(
+                            cohort_tr, cohort_opt, pending, batches, *margs,
+                            ck))
                     cohort_tr, cohort_opt, pending = outs[:3]
                     eng_bits = outs[4]
                     fresh = (np.asarray(eng_bits, np.float64)[:cfg.n_clients]
@@ -616,8 +617,8 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
                 reports = _round_reports(rplan, charged, gains)
             elif codec is None:
                 with tracer.span("device-step"):
-                    outs = round_step(cohort_tr, cohort_opt, batches,
-                                      weights)
+                    outs = jax.block_until_ready(round_step(
+                        cohort_tr, cohort_opt, batches, weights))
                 cohort_tr, cohort_opt = outs[:2]
                 if health:
                     hstats = outs[-1]
@@ -625,8 +626,8 @@ def run_pftt(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
                 reports = budget.round_reports(bits, gains)
             else:
                 with tracer.span("device-step"):
-                    outs = round_step(cohort_tr, cohort_opt, batches,
-                                      weights, ck)
+                    outs = jax.block_until_ready(round_step(
+                        cohort_tr, cohort_opt, batches, weights, ck))
                 cohort_tr, cohort_opt, eng_bits = outs[0], outs[1], outs[3]
                 if health:
                     hstats = outs[-1]

@@ -34,6 +34,7 @@ so a straggler's payload survives rounds it is not sampled in.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, List, Optional, Sequence
 
@@ -95,9 +96,18 @@ class PopulationStore:
     convention of ``repro.sharding.CohortSharding``) and returns it;
     callers ``jax.device_put`` the result themselves so sharded and
     single-device paths place it once.  ``scatter(slot, ids, tree)`` pulls
-    the device tree to host and writes the first ``len(ids)`` rows back."""
+    the device tree to host and writes the first ``len(ids)`` rows back.
 
-    def __init__(self, slots: Dict[str, object]):
+    With a ``tracer`` (``PopulationRunner`` hands the store its own), each
+    gather times its ``np.take`` as a ``gather.take`` span and counts the
+    staging bytes it filled (``gather.bytes``); each scatter times the
+    device→host pull (``scatter.pull``, counter ``scatter.bytes``) and the
+    row writes (``scatter.write``) as two spans.  Without one the store
+    times and counts nothing."""
+
+    def __init__(self, slots: Dict[str, object],
+                 tracer: Optional[SpanTracer] = None):
+        self.tracer = tracer
         self._slots = {}
         self._bufs: Dict[str, object] = {}
         n = None
@@ -118,6 +128,16 @@ class PopulationStore:
     @property
     def slots(self) -> Dict[str, object]:
         return self._slots
+
+    def _span(self, name: str, slot: str):
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.span(name, slot=slot)
+
+    def _count(self, name: str, tree) -> None:
+        if self.tracer is not None:
+            self.tracer.count(name, sum(
+                leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree)))
 
     def nbytes(self) -> int:
         return sum(leaf.nbytes
@@ -144,20 +164,28 @@ class PopulationStore:
             np.take(src, full, axis=0, out=dst)
             return dst
 
-        return jax.tree_util.tree_map(fill, tree, buf)
+        with self._span("gather.take", slot):
+            out = jax.tree_util.tree_map(fill, tree, buf)
+        self._count("gather.bytes", out)
+        return out
 
     def scatter(self, slot: str, ids: np.ndarray, device_tree) -> None:
         """Write the first ``len(ids)`` rows of ``device_tree`` back into
         ``slot`` (ghost-padded rows are dropped)."""
         ids = np.asarray(ids, np.int64)
         k = len(ids)
+        with self._span("scatter.pull", slot):
+            # np.asarray, no second host copy: the rows are copied into the
+            # store below, so no view of a (later donated) jax buffer
+            # outlives this call
+            host = jax.tree_util.tree_map(np.asarray, device_tree)
+        self._count("scatter.bytes", host)
 
         def put(dst, src):
-            # np.array copy, not np.asarray: a zero-copy view of a donated
-            # jax buffer dangles once the next round rebinds it
-            dst[ids] = np.array(src)[:k]
+            dst[ids] = src[:k]
 
-        jax.tree_util.tree_map(put, self._slots[slot], device_tree)
+        with self._span("scatter.write", slot):
+            jax.tree_util.tree_map(put, self._slots[slot], host)
 
     def zero_rows(self, slot: str, ids: Sequence[int]) -> None:
         """Zero the given rows (deferred crash-rejoin optimizer reset for
@@ -360,6 +388,7 @@ class PopulationRunner:
         # host_s/round_s keep their PR 9 meaning: sample+gather+scatter vs
         # whole-round wall
         self.tracer = tracer if tracer is not None else SpanTracer()
+        store.tracer = self.tracer        # store spans nest in the round's
         self.health = health              # round_step returns a trailing
         #                                 # health-scalar dict (obs.health)
         self.host_s = 0.0                 # sample+gather+scatter time
@@ -456,9 +485,10 @@ class PopulationRunner:
             # in the t0..t6 accounting: it is not host_s overhead)
             hstats = None
             with tracer.span("device-step"):
-                rows = [draw_batches(int(c), rnd) for c in ids]
-                rows += [rows[0]] * (self.n_rows - self.K)   # ghost rows
-                batches = stacker(rows)
+                with tracer.span("device-step.draw"):
+                    rows = [draw_batches(int(c), rnd) for c in ids]
+                    rows += [rows[0]] * (self.n_rows - self.K)  # ghost rows
+                    batches = stacker(rows)
                 w = rplan.agg_w_pre if self.dl is not None else rplan.agg_w
                 ontime = rplan.ontime if self.dl is not None \
                     else np.ones(self.N, np.float32)
@@ -469,11 +499,6 @@ class PopulationRunner:
                          self._vec(ontime[ids], 1.0))
                 if codec_key is None:
                     outs = round_step(tr_d, opt_d, pend_d, batches, *margs)
-                    tr_d, opt_d, pend_d, losses = outs[:4]
-                    if self.health:
-                        hstats = outs[4]
-                    fresh_c = np.full(self.K, (payload_bits or 0.0),
-                                      np.float64)
                 else:
                     with tracer.span("encode"):
                         rk = jax.random.fold_in(codec_key, rnd)
@@ -483,12 +508,17 @@ class PopulationRunner:
                             * (self.n_rows - self.K))
                     outs = round_step(tr_d, opt_d, pend_d, batches, *margs,
                                       self._put(ck))
-                    tr_d, opt_d, pend_d, losses, bits = outs[:5]
-                    if self.health:
-                        hstats = outs[5]
-                    fresh_c = (np.asarray(bits, np.float64)[:self.K]
+                with tracer.span("device-step.wait"):
+                    jax.block_until_ready(outs)
+                tr_d, opt_d, pend_d, losses = outs[:4]
+                if codec_key is None:
+                    fresh_c = np.full(self.K, (payload_bits or 0.0),
+                                      np.float64)
+                else:
+                    fresh_c = (np.asarray(outs[4], np.float64)[:self.K]
                                + self.act_bits)
-                jax.block_until_ready(tr_d)
+                if self.health:
+                    hstats = outs[-1]
 
             with tracer.span("scatter") as sp_scatter:
                 self.store.scatter("trainable", ids, tr_d)

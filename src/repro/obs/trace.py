@@ -8,21 +8,47 @@ events on one track nest by time containment).  The tracer ALWAYS times
 ``PopulationRunner`` keeps its ``host_s``/``round_s`` accounting and how
 the telemetry round events get their ``wall.phases`` breakdown — but it
 only *records* Chrome trace events when ``enabled=True``, so the
-disabled tracer costs two ``perf_counter`` calls and a dict add per
-span.
+disabled tracer costs two ``perf_counter`` calls, a dict add and an
+inactive ``TraceMe`` per span.
+
+Every span also opens ``jax.profiler.TraceAnnotation(name)`` around its
+body, under the span's exact name.  With no profiler session running
+that is a no-op; whenever one is (``TelemetryConfig(jax_profile=True)``,
+or any ``jax.profiler.trace``), the program's host spans land in the
+same ``.xplane.pb`` as the device ops, on one clock, so Perfetto or
+TensorBoard shows each idle gap under the host span that caused it.
+
+``count(name, n)`` adds ``n`` to a named whole-run counter (bytes moved,
+say), enabled or not; ``counts()`` returns them.  Counters are kept
+apart from the span seconds: ``totals()`` and ``pop_round()`` (and so
+``wall.phases``) hold span names only.  When enabled, each ``count``
+also appends a Chrome counter event (``"ph": "C"``) carrying the
+counter's running total.
 
 Span-name convention (used by every runner; see docs/observability.md):
 
-    round        whole-round wrapper (population runner)
-    sample       cohort sampling (population) / host batch draw (cohort)
-    plan         StalenessTracker round plan (population)
-    gather       store gather + global overlay + device_put / batch stack
-    encode       codec PRNG key build (host side of the compressed uplink)
-    device-step  the ONE fused compiled round dispatch (+block_until_ready)
-    scatter      device→store writeback + global snapshot
-    ledger       channel reports + CommLedger append
-    eval         fused cohort eval dispatch
-    checkpoint   round-level checkpoint save
+    round             whole-round wrapper (population runner)
+    sample            cohort sampling (population) / host batch draw (cohort)
+    plan              StalenessTracker round plan (population)
+    gather            store gather + global overlay + device_put / batch stack
+      gather.take     one store slot's np.take into its staging buffer
+                      (args: slot); counter gather.bytes
+    encode            codec PRNG key build (host side of the compressed uplink)
+    device-step       the ONE fused compiled round dispatch + its block
+      device-step.draw   the cohort's batch draw, ghost rows and stacking
+      device-step.wait   block_until_ready on the round's outputs
+    scatter           device→store writeback + global snapshot
+      scatter.pull    device→host copies of one slot's results (args: slot);
+                      counter scatter.bytes
+      scatter.write   the row writes of one slot into the store (args: slot)
+    ledger            channel reports + CommLedger append
+    eval              fused cohort eval dispatch
+    checkpoint        round-level checkpoint save
+
+The dotted children are the population runner's
+(``fl/population.py``); a parent's self time (the parent less its
+children) is what is left: zeroing, the global overlay, the
+``device_put`` enqueues, the dispatch, the global snapshot.
 
 ``chrome_trace()``/``write()`` emit the standard
 ``{"traceEvents": [...]}`` JSON object format: load the file in
@@ -38,7 +64,9 @@ import json
 import os
 import time
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from typing import Dict, List
+
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -58,20 +86,20 @@ class SpanTracer:
         self.enabled = enabled
         self._t0 = time.perf_counter()
         self._events: List[Dict] = []
-        self._depth = 0
         self._round_acc: Dict[str, float] = {}   # since last pop_round()
         self._total_acc: Dict[str, float] = {}   # whole run
+        self._counts: Dict[str, float] = {}      # whole run, apart from spans
 
     @contextmanager
     def span(self, name: str, **args):
         start = time.perf_counter()
         sp = Span(name, start)
-        self._depth += 1
         try:
-            yield sp
+            # the name only: the .xplane.pb event is named exactly this
+            with TraceAnnotation(name):
+                yield sp
         finally:
             end = time.perf_counter()
-            self._depth -= 1
             sp.dur = end - start
             self._round_acc[name] = self._round_acc.get(name, 0.0) + sp.dur
             self._total_acc[name] = self._total_acc.get(name, 0.0) + sp.dur
@@ -81,6 +109,17 @@ class SpanTracer:
                 if args:
                     ev["args"] = args
                 self._events.append(ev)
+
+    def count(self, name: str, n: float) -> None:
+        """Add ``n`` to the whole-run counter ``name`` (always); when
+        enabled, also record a Chrome counter event of its running total."""
+        total = self._counts.get(name, 0) + n
+        self._counts[name] = total
+        if self.enabled:
+            self._events.append(
+                {"name": name, "ph": "C", "pid": os.getpid(), "tid": 1,
+                 "ts": (time.perf_counter() - self._t0) * 1e6,
+                 "args": {"value": total}})
 
     # ---- per-round / whole-run accounting ---------------------------------
 
@@ -94,6 +133,10 @@ class SpanTracer:
     def totals(self) -> Dict[str, float]:
         """Whole-run per-span-name seconds (never reset)."""
         return {k: float(v) for k, v in self._total_acc.items()}
+
+    def counts(self) -> Dict[str, float]:
+        """Whole-run counter totals (never reset; no span names)."""
+        return dict(self._counts)
 
     # ---- Chrome trace-event JSON ------------------------------------------
 

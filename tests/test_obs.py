@@ -8,7 +8,10 @@ The contract under test:
   validator catches out-of-order / duplicate / schema-less streams.
 * ``SpanTracer`` accumulates per-phase seconds whether or not Chrome
   recording is on; recorded "X" events nest by time containment (a
-  child's [ts, ts+dur] interval lies inside its parent's).
+  child's [ts, ts+dur] interval lies inside its parent's).  Its
+  counters accumulate apart from the span seconds, and every span is
+  also a host event of the same name in a running ``jax.profiler``
+  trace.
 * The health scalars computed INSIDE the fused round body match a
   float64 host recomputation from the same inputs to ≤1e-6 — and
   enabling them does not perturb the round's state outputs.
@@ -148,6 +151,68 @@ def test_tracer_chrome_events_nest_and_order(tmp_path):
     tr.write(str(p))
     with open(p) as f:
         assert json.load(f)["traceEvents"] == ev
+
+
+def test_tracer_counts_accumulate_while_disabled():
+    tr = SpanTracer(enabled=False)
+    with tr.span("gather"):
+        tr.count("gather.bytes", 96)
+    tr.count("gather.bytes", 32)
+    tr.count("scatter.bytes", 8)
+    assert tr.counts() == {"gather.bytes": 128, "scatter.bytes": 8}
+    assert tr.chrome_trace()["traceEvents"] == []   # nothing recorded
+    # counters never mix into the span seconds
+    assert set(tr.totals()) == {"gather"}
+    assert set(tr.pop_round()) == {"gather"}
+    tr.count("gather.bytes", 1)
+    assert tr.pop_round() == {}
+    assert tr.counts()["gather.bytes"] == 129       # never reset
+
+
+def test_tracer_counter_events_only_when_enabled(tmp_path):
+    tr = SpanTracer(enabled=True)
+    with tr.span("scatter"):
+        tr.count("scatter.bytes", 10)
+        tr.count("scatter.bytes", 5)
+    ev = tr.chrome_trace()["traceEvents"]
+    counters = [e for e in ev if e["ph"] == "C"]
+    assert [e["name"] for e in counters] == ["scatter.bytes"] * 2
+    assert [e["args"] for e in counters] == [{"value": 10}, {"value": 15}]
+    span = next(e for e in ev if e["ph"] == "X")
+    for c in counters:                  # stamped on the spans' clock
+        assert span["ts"] <= c["ts"] <= span["ts"] + span["dur"] + 1e-3
+    p = tmp_path / "trace.json"
+    tr.write(str(p))
+    with open(p) as f:
+        assert json.load(f)["traceEvents"] == ev
+    assert tr.counts() == {"scatter.bytes": 15}
+    assert set(tr.totals()) == {"scatter"}
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_tracer_spans_land_in_profiler_trace(tmp_path, enabled):
+    """Every span is a host event of exactly its name in the .xplane.pb a
+    running jax.profiler session writes (args stay out of the name)."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    tr = SpanTracer(enabled=enabled)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("gather"):
+            with tr.span("gather.take", slot="opt"):
+                jnp.ones(4).block_until_ready()
+        with tr.span("device-step.wait"):
+            pass
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    host = {ev.name
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert {"gather", "gather.take", "device-step.wait"} <= host
+    assert not any("slot" in n for n in host if n.startswith("gather"))
 
 
 # ---------------------------------------------------------------------------

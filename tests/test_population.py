@@ -15,6 +15,10 @@ The contract under test (fl/population.py + wireless/scenarios.py):
   inputs).
 * ``Scenario.realize`` is a pure function of the spec: per-axis draw
   blocks keep class_probs stable when availability/mobility toggle.
+* ``PopulationRunner.run_round`` splits its ``gather``, ``device-step``
+  and ``scatter`` spans into the store's and the step's child spans and
+  byte counters, each child inside its parent, and the tracer changes
+  nothing the store holds.
 """
 import dataclasses
 import math
@@ -26,8 +30,9 @@ import pytest
 
 from repro import trees
 from repro.fl.population import (ClientSampler, PopulationConfig,
-                                 PopulationData, PopulationStore,
-                                 stacked_client_init)
+                                 PopulationData, PopulationRunner,
+                                 PopulationStore, stacked_client_init)
+from repro.obs.trace import SpanTracer
 from repro.wireless.scenarios import Scenario
 
 # ---------------------------------------------------------------------------
@@ -307,6 +312,178 @@ def test_sampled_round_matches_standalone_cohort():
         np.testing.assert_allclose(np.asarray(leaf),
                                    trees.flatten(got_pd)[k], atol=1e-6,
                                    err_msg=k)
+
+
+# ---------------------------------------------------------------------------
+# child spans and byte counters of the round driver and the store
+# ---------------------------------------------------------------------------
+
+CHILD_SPANS = {"gather.take": "gather", "device-step.draw": "device-step",
+               "device-step.wait": "device-step", "scatter.pull": "scatter",
+               "scatter.write": "scatter"}
+
+
+def _toy_runner(N=12, K=3, rounds=3, tracer=None, n_rows=None):
+    """A PopulationRunner over the toy cohort: robust round step, Rayleigh
+    uplink, no faults; ``n_rows`` > K adds ghost rows."""
+    from repro.comms import ChannelBudget
+    from repro.core.cohort import HostBatchStacker, build_supervised_round
+    from repro.core.robust import StalenessConfig, StalenessTracker
+    from repro.sharding import CohortSharding, auto_mesh
+    from repro.wireless import CommLedger, FaultPlan, RayleighChannel
+
+    local_step, opt, stacked, _ = _toy_cohort(N)
+    upload = lambda p: p.startswith("shared")
+    st_op = stacked_client_init(
+        lambda k: opt.init({"shared": {"w": jnp.zeros(3)},
+                            "local": {"v": jnp.zeros(2)}}),
+        jax.vmap(lambda i: jax.random.fold_in(jax.random.PRNGKey(0), i))(
+            jnp.arange(N)))
+    pend = jax.tree_util.tree_map(np.zeros_like,
+                                  trees.select(stacked, upload))
+    store = PopulationStore({"trainable": stacked, "opt": st_op,
+                             "pending": pend})
+    channel = RayleighChannel(seed=3)
+    cs = None if n_rows is None else CohortSharding(
+        mesh=auto_mesh((1,), ("data",)), axes=("data",), n_clients=K,
+        total=n_rows)
+    runner = PopulationRunner(
+        pop=PopulationConfig(population=N, cohort_size=K),
+        store=store,
+        global_shared=jax.tree_util.tree_map(
+            np.array, trees.select(store.row("trainable", 0), upload)),
+        upload_pred=upload, channel=channel,
+        budget=ChannelBudget(channel), ledger=CommLedger(),
+        tracker=StalenessTracker(N, StalenessConfig()),
+        trace=FaultPlan().realize(N, rounds),
+        strace=Scenario().realize(N, rounds),
+        sampler=ClientSampler("uniform", N, K, seed=7), cs=cs,
+        tracer=tracer)
+    step = build_supervised_round(local_step, upload, donate=False,
+                                  robust=True)
+
+    def draw(cid, rnd):
+        r = np.random.RandomState(100 * cid + rnd)
+        return [{"tgt": r.randn(1).astype(np.float32)} for _ in range(2)]
+
+    def one_round(rnd):
+        return runner.run_round(rnd, round_step=step,
+                                stacker=HostBatchStacker(),
+                                draw_batches=draw, local_steps=2,
+                                payload_bits=96.0)
+    return runner, store, one_round
+
+
+def test_run_round_records_child_spans_inside_parents():
+    runner, _, one_round = _toy_runner(tracer=SpanTracer(enabled=True))
+    tracer = runner.tracer
+    for rnd in range(2):
+        one_round(rnd)
+        phases = tracer.pop_round()
+        assert set(CHILD_SPANS) <= set(phases)
+        for parent in set(CHILD_SPANS.values()):
+            kids = sum(v for k, v in phases.items()
+                       if CHILD_SPANS.get(k) == parent)
+            assert kids <= phases[parent]
+    # one gather.take, scatter.pull and scatter.write per slot and round
+    ev = tracer.chrome_trace()["traceEvents"]
+    for name in ("gather.take", "scatter.pull", "scatter.write"):
+        slots = [e["args"]["slot"] for e in ev if e["name"] == name]
+        assert slots == ["trainable", "opt", "pending"] * 2
+    # every child lies inside one of its parent's events
+    spans = [e for e in ev if e["ph"] == "X"]
+    for c in spans:
+        if c["name"] not in CHILD_SPANS:
+            continue
+        assert any(p["name"] == CHILD_SPANS[c["name"]]
+                   and p["ts"] <= c["ts"]
+                   and c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-3
+                   for p in spans)
+    # host_s still reads the parents alone
+    tot = tracer.totals()
+    assert runner.host_s == pytest.approx(
+        tot["sample"] + tot["gather"] + tot["scatter"])
+
+
+@pytest.mark.parametrize("n_rows", [None, 4])
+def test_run_round_byte_counters(n_rows):
+    """gather.bytes is the staging buffers' nbytes (ghost rows included);
+    scatter.bytes is the round's result trees'."""
+    runner, store, one_round = _toy_runner(n_rows=n_rows)
+    out = one_round(0)
+    staged = sum(leaf.nbytes for buf in store._bufs.values()
+                 for leaf in jax.tree_util.tree_leaves(buf))
+    rows = n_rows or 3
+    assert staged == rows * store.nbytes() // store.n_clients
+    counts = runner.tracer.counts()
+    assert counts["gather.bytes"] == staged
+    # the three result slots have the staged slots' shapes
+    assert counts["scatter.bytes"] == staged
+    assert sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+        out["cohort_tr"])) == rows * sum(
+        leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+            store.slots["trainable"])) // store.n_clients
+    one_round(1)
+    assert runner.tracer.counts() == {k: 2 * v for k, v in counts.items()}
+
+
+def test_run_round_store_identical_with_and_without_tracing():
+    """Tracing changes nothing the store holds: an enabled tracer's round
+    and a default (disabled) one's leave bit-identical stores."""
+    _, traced, one_a = _toy_runner(tracer=SpanTracer(enabled=True))
+    _, plain, one_b = _toy_runner()
+    for rnd in range(3):
+        one_a(rnd)
+        one_b(rnd)
+    for slot in ("trainable", "opt", "pending"):
+        a = trees.flatten(traced.slots[slot])
+        b = trees.flatten(plain.slots[slot])
+        assert a.keys() == b.keys()
+        for k in a:
+            np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+def test_store_scatter_pull_then_write_matches_per_leaf_copy():
+    """A store with a tracer pulls every leaf, then writes; the store ends
+    bit-identical to a store without one and to a per-leaf
+    ``dst[ids] = np.array(src)[:k]``, and holds no view of the device
+    tree."""
+    traced, ref = _toy_store(10)
+    traced.tracer = SpanTracer()
+    plain, _ = _toy_store(10)
+    ids = np.asarray([8, 2, 5])
+    r = np.random.RandomState(1)
+    dev = jax.tree_util.tree_map(
+        lambda l: jnp.asarray(r.randn(4, *l.shape[1:]).astype(l.dtype)),
+        ref)                                     # one ghost row
+    traced.scatter("trainable", ids, dev)
+    plain.scatter("trainable", ids, dev)
+    for k, leaf in trees.flatten(ref).items():
+        want = leaf.copy()
+        want[ids] = np.array(trees.flatten(dev)[k])[:3]
+        np.testing.assert_array_equal(
+            trees.flatten(traced.slots["trainable"])[k], want)
+        np.testing.assert_array_equal(
+            trees.flatten(plain.slots["trainable"])[k], want)
+    for leaf, d in zip(jax.tree_util.tree_leaves(traced.slots["trainable"]),
+                       jax.tree_util.tree_leaves(dev)):
+        assert not np.shares_memory(leaf, np.asarray(d))   # rows copied in
+    assert traced.tracer.counts() == {"scatter.bytes": sum(
+        l.nbytes for l in jax.tree_util.tree_leaves(dev))}
+    assert set(traced.tracer.totals()) == {"scatter.pull", "scatter.write"}
+
+
+def test_store_without_tracer_times_nothing():
+    store, _ = _toy_store(6)
+    assert store.tracer is None
+    g = store.gather("trainable", np.asarray([1, 2]), pad_to=3)
+    store.scatter("trainable", np.asarray([1, 2]),
+                  jax.tree_util.tree_map(jnp.asarray, g))
+    tracer = SpanTracer()
+    store.tracer = tracer
+    store.gather("trainable", np.asarray([4]))
+    assert set(tracer.totals()) == {"gather.take"}
+    assert tracer.counts() == {"gather.bytes": store.nbytes() // 6}
 
 
 # ---------------------------------------------------------------------------
