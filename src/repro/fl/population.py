@@ -50,10 +50,17 @@ SAMPLER_KINDS = ("uniform", "availability")
 
 
 def _writable(leaf) -> np.ndarray:
-    """Host numpy array the store may mutate (``np.asarray`` of a jax
-    array is a READ-ONLY view — scatter would fail on it)."""
+    """Host numpy array the store may mutate and gather rows from.
+
+    It must be writable (``np.asarray`` of a jax array is a READ-ONLY view
+    — scatter would fail on it) and C-contiguous: an array pulled from a
+    TPU keeps the device's layout, and ``np.take(..., out=)`` copies a
+    non-C-contiguous source whole before it reads the rows it needs.  Any
+    other input is copied once, in C order."""
     a = np.asarray(leaf)
-    return a if a.flags.writeable else np.array(a)
+    if a.flags.writeable and a.flags.c_contiguous:
+        return a
+    return np.array(a, order="C")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,16 +110,23 @@ class PopulationStore:
     staging bytes it filled (``gather.bytes``); each scatter times the
     device→host pull (``scatter.pull``, counter ``scatter.bytes``) and the
     row writes (``scatter.write``) as two spans.  Without one the store
-    times and counts nothing."""
+    times and counts nothing.
+
+    Every leaf the store holds is host-owned, writable and C-contiguous
+    (``_writable``).  ``relaid_bytes`` sums the bytes of the inputs that
+    were not C-contiguous and so were copied into C order, over
+    ``__init__`` and every ``load_checkpoint_tree``; the runner counts it
+    as ``store.relaid_bytes``."""
 
     def __init__(self, slots: Dict[str, object],
                  tracer: Optional[SpanTracer] = None):
         self.tracer = tracer
+        self.relaid_bytes = 0
         self._slots = {}
         self._bufs: Dict[str, object] = {}
         n = None
         for name, tree in slots.items():
-            tree = jax.tree_util.tree_map(_writable, tree)
+            tree = self._own(tree)
             for leaf in jax.tree_util.tree_leaves(tree):
                 n = leaf.shape[0] if n is None else n
                 assert leaf.shape[0] == n, \
@@ -128,6 +142,16 @@ class PopulationStore:
     @property
     def slots(self) -> Dict[str, object]:
         return self._slots
+
+    def _own(self, tree):
+        """``tree`` with every leaf ``_writable``; the non-C-contiguous
+        leaves' bytes are added to ``relaid_bytes``."""
+        def own(leaf):
+            a = np.asarray(leaf)
+            if not a.flags.c_contiguous:
+                self.relaid_bytes += a.nbytes
+            return _writable(a)
+        return jax.tree_util.tree_map(own, tree)
 
     def _span(self, name: str, slot: str):
         if self.tracer is None:
@@ -207,9 +231,12 @@ class PopulationStore:
         return dict(self._slots)
 
     def load_checkpoint_tree(self, tree) -> None:
+        before = self.relaid_bytes
         for name in self._slots:
-            self._slots[name] = jax.tree_util.tree_map(
-                _writable, tree[name])
+            self._slots[name] = self._own(tree[name])
+        if self.tracer is not None:
+            self.tracer.count("store.relaid_bytes",
+                              self.relaid_bytes - before)
 
 
 class ClientSampler:
@@ -389,6 +416,7 @@ class PopulationRunner:
         # whole-round wall
         self.tracer = tracer if tracer is not None else SpanTracer()
         store.tracer = self.tracer        # store spans nest in the round's
+        self.tracer.count("store.relaid_bytes", store.relaid_bytes)
         self.health = health              # round_step returns a trailing
         #                                 # health-scalar dict (obs.health)
         self.host_s = 0.0                 # sample+gather+scatter time

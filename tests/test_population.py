@@ -15,6 +15,10 @@ The contract under test (fl/population.py + wireless/scenarios.py):
   inputs).
 * ``Scenario.realize`` is a pure function of the spec: per-axis draw
   blocks keep class_probs stable when availability/mobility toggle.
+* Every leaf the store holds is writable and C-contiguous, whatever the
+  layout it was handed (a TPU pull keeps the device's); ``relaid_bytes``
+  counts the bytes copied into C order, and a C-ordered numpy input costs
+  nothing.
 * ``PopulationRunner.run_round`` splits its ``gather``, ``device-step``
   and ``scatter`` spans into the store's and the step's child spans and
   byte counters, each child inside its parent, and the tracer changes
@@ -217,6 +221,86 @@ def test_store_checkpoint_roundtrip():
     store2.zero_rows("trainable", [0])
 
 
+def _transposed_tree(n, writable, seed=0):
+    """A tree laid out as a TPU pull is: leaf ``a/w`` (n, 3, 4, 8) with its
+    last two axes swapped in memory (not C-contiguous), leaf ``b`` a
+    C-contiguous (n, 5); both read-only unless ``writable``."""
+    r = np.random.RandomState(seed)
+    tree = {"a": {"w": np.swapaxes(
+                r.randn(n, 3, 8, 4).astype(np.float32), -1, -2)},
+            "b": r.randn(n, 5).astype(np.float32)}
+    for leaf in jax.tree_util.tree_leaves(tree):
+        assert leaf.flags.writeable
+        leaf.flags.writeable = writable
+    assert not tree["a"]["w"].flags.c_contiguous
+    return tree
+
+
+def _assert_host_c(store, slot="trainable"):
+    for leaf in jax.tree_util.tree_leaves(store.slots[slot]):
+        assert leaf.flags.c_contiguous and leaf.flags.writeable
+
+
+@pytest.mark.parametrize("writable", [False, True])
+def test_store_lays_out_transposed_source_c_contiguous(writable):
+    src = _transposed_tree(10, writable)
+    store = PopulationStore({"trainable": src})
+    _assert_host_c(store)
+    for k, leaf in trees.flatten(src).items():
+        np.testing.assert_array_equal(
+            trees.flatten(store.slots["trainable"])[k], leaf, err_msg=k)
+    # only the non-C leaf was re-laid out; the C leaf, read-only or not,
+    # is no re-layout
+    assert store.relaid_bytes == src["a"]["w"].nbytes
+    if writable:                       # a writable C leaf is kept as is
+        assert store.slots["trainable"]["b"] is src["b"]
+
+
+def test_store_of_c_source_relays_nothing():
+    store, ref = _toy_store(8)
+    assert store.relaid_bytes == 0
+    for k, leaf in trees.flatten(ref).items():
+        assert trees.flatten(store.slots["trainable"])[k] is leaf
+
+
+@pytest.mark.parametrize("writable", [False, True])
+def test_store_transposed_source_gather_scatter_bit_exact(writable):
+    """On a store built from a transposed source, gather (ghost rows
+    included) and scatter equal plain fancy indexing bit for bit."""
+    src = _transposed_tree(12, writable)
+    store = PopulationStore({"trainable": src})
+    ref = jax.tree_util.tree_map(np.array, src)
+    ids = np.asarray([9, 2, 6])
+    full = np.asarray([9, 2, 6, 9, 9])
+    g = store.gather("trainable", ids, pad_to=5)
+    for k, leaf in trees.flatten(ref).items():
+        np.testing.assert_array_equal(trees.flatten(g)[k], leaf[full],
+                                      err_msg=k)
+    r = np.random.RandomState(3)
+    new = jax.tree_util.tree_map(
+        lambda l: r.randn(5, *l.shape[1:]).astype(l.dtype), ref)
+    store.scatter("trainable", ids,
+                  jax.tree_util.tree_map(jnp.asarray, new))
+    for k, leaf in trees.flatten(ref).items():
+        leaf[ids] = trees.flatten(new)[k][:3]
+        np.testing.assert_array_equal(
+            trees.flatten(store.slots["trainable"])[k], leaf, err_msg=k)
+    _assert_host_c(store)
+
+
+def test_store_load_checkpoint_tree_relays_transposed_tree():
+    store, _ = _toy_store(10)
+    store.tracer = SpanTracer()
+    src = _transposed_tree(10, writable=False, seed=4)
+    store.load_checkpoint_tree({"trainable": src})
+    _assert_host_c(store)
+    np.testing.assert_array_equal(store.slots["trainable"]["a"]["w"],
+                                  src["a"]["w"])
+    assert store.relaid_bytes == src["a"]["w"].nbytes
+    assert store.tracer.counts() == {
+        "store.relaid_bytes": src["a"]["w"].nbytes}
+
+
 def test_stacked_client_init_broadcasts_constants():
     keys = jax.vmap(lambda i: jax.random.fold_in(jax.random.PRNGKey(0), i))(
         jnp.arange(6))
@@ -323,9 +407,11 @@ CHILD_SPANS = {"gather.take": "gather", "device-step.draw": "device-step",
                "scatter.write": "scatter"}
 
 
-def _toy_runner(N=12, K=3, rounds=3, tracer=None, n_rows=None):
+def _toy_runner(N=12, K=3, rounds=3, tracer=None, n_rows=None,
+                fortran=False):
     """A PopulationRunner over the toy cohort: robust round step, Rayleigh
-    uplink, no faults; ``n_rows`` > K adds ghost rows."""
+    uplink, no faults; ``n_rows`` > K adds ghost rows; ``fortran`` hands
+    the store column-major trainable and optimizer leaves."""
     from repro.comms import ChannelBudget
     from repro.core.cohort import HostBatchStacker, build_supervised_round
     from repro.core.robust import StalenessConfig, StalenessTracker
@@ -339,6 +425,9 @@ def _toy_runner(N=12, K=3, rounds=3, tracer=None, n_rows=None):
                             "local": {"v": jnp.zeros(2)}}),
         jax.vmap(lambda i: jax.random.fold_in(jax.random.PRNGKey(0), i))(
             jnp.arange(N)))
+    if fortran:
+        stacked, st_op = jax.tree_util.tree_map(np.asfortranarray,
+                                                (stacked, st_op))
     pend = jax.tree_util.tree_map(np.zeros_like,
                                   trees.select(stacked, upload))
     store = PopulationStore({"trainable": stacked, "opt": st_op,
@@ -425,6 +514,44 @@ def test_run_round_byte_counters(n_rows):
             store.slots["trainable"])) // store.n_clients
     one_round(1)
     assert runner.tracer.counts() == {k: 2 * v for k, v in counts.items()}
+
+
+@pytest.mark.parametrize("fortran", [False, True])
+def test_runner_counts_store_relaid_bytes(fortran):
+    """Handing the store its tracer counts, once, the bytes the store
+    re-laid out: a store built from C-ordered host arrays reads 0, one
+    built from column-major leaves reads their bytes."""
+    runner, store, one_round = _toy_runner(fortran=fortran)
+    # column-major leaves of two or more axes are not C-contiguous
+    want = sum(leaf.nbytes for tree in store.slots.values()
+               for leaf in jax.tree_util.tree_leaves(tree)
+               if fortran and leaf.ndim > 1)
+    assert (want > 0) == fortran
+    assert store.relaid_bytes == want
+    assert runner.tracer.counts()["store.relaid_bytes"] == \
+        store.relaid_bytes
+    one_round(0)
+    assert runner.tracer.counts()["store.relaid_bytes"] == \
+        store.relaid_bytes
+
+
+def test_run_round_store_identical_for_any_input_layout():
+    """Rounds over a store built from column-major leaves (as a TPU pull
+    may be laid out; the pending slot from ``np.zeros_like`` of them,
+    writable) leave a store bit-identical to the C-ordered one's, every
+    leaf C-contiguous."""
+    _, fort, one_a = _toy_runner(fortran=True)
+    _, plain, one_b = _toy_runner()
+    for rnd in range(3):
+        one_a(rnd)
+        one_b(rnd)
+    for slot in ("trainable", "opt", "pending"):
+        _assert_host_c(fort, slot)
+        a = trees.flatten(fort.slots[slot])
+        b = trees.flatten(plain.slots[slot])
+        assert a.keys() == b.keys()
+        for k in a:
+            np.testing.assert_array_equal(a[k], b[k], err_msg=k)
 
 
 def test_run_round_store_identical_with_and_without_tracing():
