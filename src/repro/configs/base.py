@@ -67,11 +67,28 @@ class Stage:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """``n_experts`` is the router's width (the published count).
+    ``n_held`` > 0 says this chip holds experts ``first_held ..
+    first_held + n_held - 1`` only: the layer routes over all
+    ``n_experts``, computes its held experts' part dropless through the
+    grouped matmul (``models/moe.py::moe_held``), and the expert slabs
+    hold ``n_held`` experts.  0 keeps the capacity path, every expert
+    held (or sharded over the mesh's model axis)."""
+
     n_experts: int
     top_k: int
     d_ff: int                     # per-expert hidden width
     n_shared_experts: int = 0     # deepseek-v2 style always-on experts
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True   # renormalize the top-k gate weights
+    routed_scaling: float = 1.0   # multiplies the routed gate weights
+    n_held: int = 0
+    first_held: int = 0
+
+    @property
+    def n_slab(self) -> int:
+        """Experts in this chip's weight slabs."""
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,10 +104,22 @@ class SSMConfig:
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: q = x·wq, no compression
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling``, type ``yarn``)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,6 +157,7 @@ class ModelConfig:
     act: str = "swiglu"           # swiglu | geglu | gelu
     pos: str = "rope"             # rope | learned
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[RopeScaling] = None   # YaRN, MLA's rope part only
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     embed_scale: bool = False     # gemma-style sqrt(d_model) embedding scale
@@ -248,12 +278,16 @@ class ModelConfig:
             stages.append(Stage(pattern, min(s.repeats, repeats), s.stream))
         moe = None
         if self.moe is not None:
-            moe = MoEConfig(
-                n_experts=min(self.moe.n_experts, n_experts),
+            n_e = min(self.moe.n_experts, n_experts)
+            moe = dataclasses.replace(
+                self.moe,
+                n_experts=n_e,
                 top_k=min(self.moe.top_k, 2),
                 d_ff=max(32, int(self.moe.d_ff * scale)),
                 n_shared_experts=min(self.moe.n_shared_experts, 1),
                 capacity_factor=2.0,
+                n_held=min(self.moe.n_held, n_e),
+                first_held=0,
             )
         ssm = None
         if self.ssm is not None:
@@ -261,7 +295,9 @@ class ModelConfig:
                             chunk=32, conv_width=self.ssm.conv_width)
         mla = None
         if self.mla is not None:
-            mla = MLAConfig(kv_lora_rank=32, q_lora_rank=48, rope_head_dim=16,
+            mla = MLAConfig(kv_lora_rank=32,
+                            q_lora_rank=48 if self.mla.q_lora_rank else None,
+                            rope_head_dim=16,
                             nope_head_dim=hd, v_head_dim=hd)
         sparse = self.sparse_attn
         if sparse is not None:
@@ -350,5 +386,5 @@ def _load_all():
     from repro.configs import (  # noqa: F401
         whisper_base, jamba_v0_1_52b, mamba2_1_3b, gemma3_12b, dbrx_132b,
         tinyllama_1_1b, llama3_2_1b, deepseek_67b, internvl2_26b,
-        deepseek_v2_236b, gpt2_small, roberta_base,
+        deepseek_v2_236b, deepseek_v2_lite, gpt2_small, roberta_base,
     )

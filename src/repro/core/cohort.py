@@ -354,13 +354,14 @@ def build_supervised_round(local_step_fn: Callable,
         # deadline mask: a late arrival merges at weight 0 (it stays in
         # pending and retransmits with its staleness discount next chance)
         agg_w = agg_w * ontime_m
-        agg = agg_fn(send, agg_w, axis_names=axes)
+        with jax.named_scope("aggregate"):   # the weighted psum, sharded
+            agg = agg_fn(send, agg_w, axis_names=axes)
+            wsum = agg_w.sum()
+            n_del = (agg_w > 0).astype(jnp.float32).sum()
+            if axes is not None:
+                wsum = jax.lax.psum(wsum, axes)
+                n_del = jax.lax.psum(n_del, axes)
         flat_agg = trees.flatten(agg)
-        wsum = agg_w.sum()
-        n_del = (agg_w > 0).astype(jnp.float32).sum()
-        if axes is not None:
-            wsum = jax.lax.psum(wsum, axes)
-            n_del = jax.lax.psum(n_del, axes)
         # nothing delivered (or an under-quorum cohort) → no-op update
         gate = jnp.logical_and(wsum > 0, n_del >= min_quorum)
 
@@ -417,11 +418,12 @@ def build_supervised_round(local_step_fn: Callable,
             uploaded, bits = jax.vmap(
                 lambda k, t, rf: codec_mod.roundtrip(codec, k, t, ref=rf)
             )(keys, uploaded, ref)
-        agg = agg_fn(uploaded, weights, axis_names=axes)
+        with jax.named_scope("aggregate"):   # the weighted psum, sharded
+            agg = agg_fn(uploaded, weights, axis_names=axes)
+            wsum = weights.sum()
+            if axes is not None:
+                wsum = jax.lax.psum(wsum, axes)
         flat_agg = trees.flatten(agg)
-        wsum = weights.sum()
-        if axes is not None:
-            wsum = jax.lax.psum(wsum, axes)
         gate = wsum > 0                    # all-outage round → keep local
 
         def put(path, loc):

@@ -21,7 +21,7 @@ from repro.models import attention as attn
 from repro.models import mla as mla_mod
 from repro.models import ssm as ssm_mod
 from repro.models.mlp import init_mlp, mlp
-from repro.models.moe import init_moe, moe_ffn, moe_ffn_a2a
+from repro.models.moe import init_moe, moe_layer
 from repro.models.norms import apply_norm
 from repro.models.peft import LoraProj, has_factors, merge_factors
 from repro.models.rope import apply_rope
@@ -198,12 +198,13 @@ def apply_layer_seq(x, lp, kind: LayerKind, ctx: LayerCtx, lora=None):
         mf = _sub(lora, "mixer")
         impl = ctx.impl if ctx.impl != "auto" else (
             "dense" if x.shape[1] <= 2048 else "chunked")
-        y, (ckv, kpe) = mla_mod.mla_seq(
-            xn, lp["mixer"], cfg.mla, cfg.n_heads, ctx.positions,
-            cfg.rope_theta, cfg.norm_eps, causal=ctx.causal, impl=impl,
-            sparse_cfg=cfg.sparse_attn, q_offset=ctx.q_offset,
-            causal_skip=ctx.opts.get("causal_skip", False),
-            **_lkw(ctx, mf))
+        with jax.named_scope("mla"):
+            y, (ckv, kpe) = mla_mod.mla_seq(
+                xn, lp["mixer"], cfg.mla, cfg.n_heads, ctx.positions,
+                cfg.rope_theta, cfg.norm_eps, causal=ctx.causal, impl=impl,
+                sparse_cfg=cfg.sparse_attn, q_offset=ctx.q_offset,
+                causal_skip=ctx.opts.get("causal_skip", False),
+                rope_scaling=cfg.rope_scaling, **_lkw(ctx, mf))
         x = x + y
         cache_entry = {"ckv": ckv, "kpe": kpe}
     elif kind.mixer == "mamba":
@@ -229,14 +230,13 @@ def apply_layer_seq(x, lp, kind: LayerKind, ctx: LayerCtx, lora=None):
             x = x + mlp(xn2, lp["ff"], cfg.act, lora=_sub(lora, "ff"),
                         scale=ctx.lora_scale,
                         backend=ctx.opts.get("lora_backend", "jnp"))
-        elif ctx.opts.get("moe_a2a"):
-            fp = merge_factors(lp["ff"], _sub(lora, "ff"), ctx.lora_scale)
-            y, aux = moe_ffn_a2a(xn2, fp, cfg.moe, ctx.meshctx, cfg.act)
-            x = x + y
         else:
             fp = merge_factors(lp["ff"], _sub(lora, "ff"), ctx.lora_scale)
-            y, aux = moe_ffn(xn2, fp, cfg.moe, ctx.meshctx, cfg.act)
+            y, aux, counts = moe_layer(xn2, fp, cfg.moe, ctx.meshctx, cfg.act,
+                                       a2a=ctx.opts.get("moe_a2a", False))
             x = x + y
+            if counts:
+                cache_entry = {**(cache_entry or {}), **counts}
     if "adapter" in lp:  # PFTT universal adapter (bottleneck + residual)
         from repro.models.peft import adapter_fwd
         x = adapter_fwd(x, lp["adapter"])
@@ -262,6 +262,7 @@ def apply_layer_decode(x, lp, kind: LayerKind, cache, ctx: LayerCtx,
     pos = ctx.pos
     xn = apply_norm(x, lp["norm1"], cfg.norm, cfg.norm_eps)
     new_cache = cache
+    moe_counts = {}
 
     def _ff(x, lq=lora):
         xn2 = apply_norm(x, lp["norm2"], cfg.norm, cfg.norm_eps)
@@ -270,8 +271,14 @@ def apply_layer_decode(x, lp, kind: LayerKind, cache, ctx: LayerCtx,
                            scale=ctx.lora_scale,
                            backend=ctx.opts.get("lora_backend", "jnp"))
         fp = merge_factors(lp["ff"], _sub(lq, "ff"), ctx.lora_scale)
-        y, _ = moe_ffn(xn2, fp, cfg.moe, ctx.meshctx, cfg.act)
+        y, _, counts = moe_layer(xn2, fp, cfg.moe, ctx.meshctx, cfg.act)
+        moe_counts.update(counts)
         return x + y
+
+    def _counted(c):
+        """The cache with this step's expert-layer counters added to the
+        running totals that prefill started."""
+        return dict(c, **{k: cache[k] + v for k, v in moe_counts.items()})
 
     if kind.mixer in ("attn", "local", "dec"):
         mf = _sub(lora, "mixer")
@@ -291,7 +298,7 @@ def apply_layer_decode(x, lp, kind: LayerKind, cache, ctx: LayerCtx,
             if "adapter" in lp:
                 from repro.models.peft import adapter_fwd
                 x = adapter_fwd(x, lp["adapter"])
-            return x, new_cache
+            return x, _counted(new_cache)
         sc = cache["k"].shape[1]
         ring = kind.mixer == "local" and cfg.window > 0 and sc <= cfg.window
         slot = jnp.mod(pos, sc) if ring else jnp.minimum(pos, sc - 1)
@@ -319,15 +326,19 @@ def apply_layer_decode(x, lp, kind: LayerKind, cache, ctx: LayerCtx,
                           _sub(cf, "wo"), ctx)
     elif kind.mixer == "mla":
         mf = _sub(lora, "mixer")
-        c_kv, k_pe = mla_mod._compress_kv(
-            xn, lp["mixer"], cfg.mla, jnp.full((x.shape[0], 1), pos),
-            cfg.rope_theta, cfg.norm_eps, **_lkw(ctx, mf))
-        ckv = _cache_write(cache["ckv"], c_kv, pos)
-        kpe = _cache_write(cache["kpe"], k_pe, pos)
-        sparse = cfg.sparse_attn if ctx.impl == "sparse" else None
-        y = mla_mod.mla_decode(xn, lp["mixer"], cfg.mla, cfg.n_heads, pos,
-                               cfg.rope_theta, cfg.norm_eps, ckv, kpe,
-                               sparse_cfg=sparse, **_lkw(ctx, mf))
+        with jax.named_scope("mla"):
+            c_kv, k_pe = mla_mod._compress_kv(
+                xn, lp["mixer"], cfg.mla, jnp.full((x.shape[0], 1), pos),
+                cfg.rope_theta, cfg.norm_eps,
+                rope_scaling=cfg.rope_scaling, **_lkw(ctx, mf))
+            ckv = _cache_write(cache["ckv"], c_kv, pos)
+            kpe = _cache_write(cache["kpe"], k_pe, pos)
+            sparse = cfg.sparse_attn if ctx.impl == "sparse" else None
+            y = mla_mod.mla_decode(xn, lp["mixer"], cfg.mla, cfg.n_heads,
+                                   pos, cfg.rope_theta, cfg.norm_eps, ckv,
+                                   kpe, sparse_cfg=sparse,
+                                   rope_scaling=cfg.rope_scaling,
+                                   **_lkw(ctx, mf))
         x = x + y
         new_cache = dict(cache, ckv=ckv, kpe=kpe)
     elif kind.mixer == "mamba":
@@ -343,7 +354,7 @@ def apply_layer_decode(x, lp, kind: LayerKind, cache, ctx: LayerCtx,
     if "adapter" in lp:
         from repro.models.peft import adapter_fwd
         x = adapter_fwd(x, lp["adapter"])
-    return x, new_cache
+    return x, _counted(new_cache)
 
 
 # ---------------------------------------------------------------------------
@@ -351,9 +362,27 @@ def apply_layer_decode(x, lp, kind: LayerKind, cache, ctx: LayerCtx,
 # ---------------------------------------------------------------------------
 
 
+def moe_counter_shapes(n_held: int):
+    """Cache entries of a held-expert MoE layer's counters (running
+    totals since prefill, ``moe.moe_held``): not positions of the
+    sequence."""
+    return {"moe_rows": ((n_held,), jnp.int32),
+            "moe_hits": ((), jnp.int32),
+            "moe_dropped": ((), jnp.int32)}
+
+
 def layer_cache_shape(cfg: ModelConfig, kind: LayerKind, batch: int,
                       cache_len: int, dtype, sparse_kv: bool = False):
-    """Abstract cache entry for one layer (no leading repeat axis)."""
+    """Abstract cache entry for one layer (no leading repeat axis): the
+    mixer's state, and a held-expert MoE layer's counters."""
+    c = _mixer_cache_shape(cfg, kind, batch, cache_len, dtype, sparse_kv)
+    if kind.ff == "moe" and cfg.moe.n_held:
+        c = {**c, **moe_counter_shapes(cfg.moe.n_held)}
+    return c
+
+
+def _mixer_cache_shape(cfg: ModelConfig, kind: LayerKind, batch: int,
+                       cache_len: int, dtype, sparse_kv: bool):
     if sparse_kv and kind.mixer == "attn" and cfg.sparse_attn is not None:
         from repro.models.attention import sparse_kv_layout
         _, _, ring_slots, n_pers = sparse_kv_layout(cache_len, cfg.sparse_attn)
@@ -403,9 +432,10 @@ def layer_param_count(cfg: ModelConfig, kind: LayerKind,
     elif kind.mixer == "mla":
         m = cfg.mla
         qk = m.nope_head_dim + m.rope_head_dim
-        n += (d * m.q_lora_rank + m.q_lora_rank
-              + m.q_lora_rank * cfg.n_heads * qk
-              + d * (m.kv_lora_rank + m.rope_head_dim) + m.kv_lora_rank
+        q = (d * cfg.n_heads * qk if m.q_lora_rank is None else
+             d * m.q_lora_rank + m.q_lora_rank
+             + m.q_lora_rank * cfg.n_heads * qk)
+        n += (q + d * (m.kv_lora_rank + m.rope_head_dim) + m.kv_lora_rank
               + m.kv_lora_rank * cfg.n_heads * (m.nope_head_dim + m.v_head_dim)
               + cfg.n_heads * m.v_head_dim * d)
     elif kind.mixer == "mamba":
@@ -422,7 +452,7 @@ def layer_param_count(cfg: ModelConfig, kind: LayerKind,
     elif kind.ff == "moe":
         m = cfg.moe
         mult = 3 if cfg.act in ("swiglu", "geglu") else 2
-        e = m.top_k if active_only else m.n_experts
+        e = min(m.top_k, m.n_slab) if active_only else m.n_slab
         n += d + d * m.n_experts + e * mult * d * m.d_ff
         if m.n_shared_experts:
             n += mult * d * (m.n_shared_experts * m.d_ff)

@@ -12,6 +12,21 @@ topology; the psum is the same collective a tensor-parallel dense FF needs.
 Routing is token-choice top-k with capacity dropping (sort-based dispatch
 table, gather/scatter with ``mode='drop'``).  For tiny token counts (decode)
 capacity is set to T·k → dropless.
+
+One chip's expert-parallel share (``MoEConfig.n_held`` > 0, ``moe_held``):
+the chip holds experts ``first_held .. first_held + n_held - 1`` of a
+layer whose experts are divided over several chips.  It routes every
+token over all ``n_experts`` (softmax in float32, top-k, renormalized only
+if ``norm_topk_prob``, times ``routed_scaling``) and computes the part of
+the result its held experts give, ``Σ_{e ∈ topk ∩ held} w_e·FFN_e(x)``,
+plus the shared experts.  Nothing stands in for the other chips' experts,
+and on one chip there is no exchange.  Routing is dropless: the (token,
+held expert) pairs are sorted by expert and the held experts run as one
+grouped matmul (``kernels/moe_gmm``) over the rows actually routed, within
+the static bound ``t·min(k, n_held)`` rows.  The layer also returns its
+counters: rows routed to each held expert, the grouped calls' experts hit,
+and pairs dropped (0 by construction).  Named scopes for the device
+trace: ``moe`` with ``route``, ``gmm``, ``combine`` and ``shared``.
 """
 from __future__ import annotations
 
@@ -23,8 +38,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
+from repro.kernels.moe_gmm.ops import moe_gmm
 from repro.models.mlp import act_fn
 from repro.sharding import MeshCtx
+
+# tokens per grouped call: longer token sets (prefill) run in chunks, which
+# bounds the sorted rows' temporaries at t·min(k, n_held) per chunk
+HELD_CHUNK_TOKENS = 16384
 
 
 def _capacity(n_tokens: int, cfg: MoEConfig) -> int:
@@ -143,12 +163,104 @@ def moe_ffn(x, params, cfg: MoEConfig, meshctx: MeshCtx, act: str):
     return y, aux
 
 
+def _held_rows(xt, w, idx, params, cfg: MoEConfig, act: str):
+    """The held experts' part for one set of tokens.  xt: (t, d); w, idx:
+    (t, k) gate weights and expert ids.  Returns (y (t, d), rows per held
+    expert (n_held,), experts hit, pairs dropped)."""
+    t, d = xt.shape
+    k, eh = cfg.top_k, cfg.n_held
+    local = idx - cfg.first_held
+    key = jnp.where((local >= 0) & (local < eh), local, eh).reshape(-1)
+    rows = jnp.zeros((eh,), jnp.int32).at[key].add(1, mode="drop")
+    bound = t * min(k, eh)              # a token holds ≤ min(k, eh) pairs
+    m = -(-bound // 128) * 128          # the kernel's row tiles
+    order = jnp.argsort(key, stable=True)[:min(m, t * k)]
+    if order.shape[0] < m:              # tiny token sets: pad the rows
+        order = jnp.concatenate(
+            [order, jnp.full((m - order.shape[0],), t * k, order.dtype)])
+    n_pairs = rows.sum()
+    valid = jnp.arange(m) < n_pairs
+    tok = jnp.minimum(order // k, t - 1)
+    xs = xt[tok]
+    with jax.named_scope("gmm"):
+        if act in ("swiglu", "geglu"):
+            h = act_fn(act)(moe_gmm(xs, params["wg"], rows)) * \
+                moe_gmm(xs, params["wu"], rows)
+        else:
+            h = act_fn(act)(moe_gmm(xs, params["wu"], rows))
+        ys = moe_gmm(h, params["wd"], rows)
+    with jax.named_scope("combine"):
+        wr = jnp.concatenate([w.reshape(-1), jnp.zeros((1,), w.dtype)])[order]
+        contrib = jnp.where(valid[:, None],
+                            ys.astype(jnp.float32) * wr[:, None], 0.0)
+        y = jnp.zeros((t, d), jnp.float32).at[tok].add(contrib)
+    return (y.astype(xt.dtype), rows, (rows > 0).sum().astype(jnp.int32),
+            jnp.maximum(n_pairs - bound, 0).astype(jnp.int32))
+
+
+def moe_held(x, params, cfg: MoEConfig, act: str):
+    """One chip's share of the expert layer (module docstring).  x: (B, S,
+    d).  Returns (y, aux, counts) with counts ``moe_rows`` (n_held,),
+    ``moe_hits`` and ``moe_dropped`` (int32 scalars), summed over the
+    layer's grouped calls."""
+    b, s, d = x.shape
+    t = b * s
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe"):
+        with jax.named_scope("route"):
+            gates = jax.nn.softmax(xt.astype(jnp.float32)
+                                   @ params["router"].astype(jnp.float32))
+            w, idx = jax.lax.top_k(gates, cfg.top_k)
+            if cfg.norm_topk_prob:
+                w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+            if cfg.routed_scaling != 1.0:
+                w = w * cfg.routed_scaling
+        experts = {n: params[n] for n in ("wg", "wu", "wd") if n in params}
+        c = HELD_CHUNK_TOKENS if t > HELD_CHUNK_TOKENS else t
+        if t % c:
+            c = t
+        if c == t:
+            y, rows, hits, dropped = _held_rows(xt, w, idx, experts, cfg, act)
+        else:
+            ys, rows, hits, dropped = jax.lax.map(
+                lambda a: _held_rows(*a, experts, cfg, act),
+                (xt.reshape(t // c, c, d), w.reshape(t // c, c, -1),
+                 idx.reshape(t // c, c, -1)))
+            y = ys.reshape(t, d)
+            rows, hits, dropped = rows.sum(0), hits.sum(), dropped.sum()
+        y = y.reshape(b, s, d)
+        if cfg.n_shared_experts > 0:
+            from repro.models.mlp import mlp
+            with jax.named_scope("shared"):
+                y = y + mlp(x, params["shared"], act)
+        frac_routed = jnp.zeros((cfg.n_experts,), jnp.float32).at[
+            idx.reshape(-1)].add(1.0) / (t * cfg.top_k)
+        aux = cfg.n_experts * jnp.sum(frac_routed * gates.mean(0))
+    return y, aux, {"moe_rows": rows, "moe_hits": hits,
+                    "moe_dropped": dropped}
+
+
+def moe_layer(x, params, cfg: MoEConfig, meshctx: MeshCtx, act: str,
+              a2a: bool = False):
+    """The expert layer for the share of experts ``cfg`` states: this
+    chip's held experts (``moe_held``) where ``n_held`` is set, else every
+    expert through the capacity path (``moe_ffn``, or ``moe_ffn_a2a``
+    with ``a2a``).  Returns (y, aux, counts); counts are empty but for
+    held experts."""
+    if cfg.n_held:
+        return moe_held(x, params, cfg, act)
+    fn = moe_ffn_a2a if a2a else moe_ffn
+    y, aux = fn(x, params, cfg, meshctx, act)
+    return y, aux, {}
+
+
 def init_moe(key, d_model: int, cfg: MoEConfig, act: str, dtype):
     ks = jax.random.split(key, 5)
-    e, f = cfg.n_experts, cfg.d_ff
+    e, f = cfg.n_slab, cfg.d_ff
     std_in, std_out = d_model ** -0.5, f ** -0.5
     p = {
-        "router": (jax.random.normal(ks[0], (d_model, e)) * std_in).astype(jnp.float32),
+        "router": (jax.random.normal(ks[0], (d_model, cfg.n_experts))
+                   * std_in).astype(jnp.float32),
         "wg": (jax.random.normal(ks[1], (e, d_model, f)) * std_in).astype(dtype),
         "wu": (jax.random.normal(ks[2], (e, d_model, f)) * std_in).astype(dtype),
         "wd": (jax.random.normal(ks[3], (e, f, d_model)) * std_out).astype(dtype),
@@ -280,3 +392,31 @@ def moe_ffn_a2a(x, params, cfg: MoEConfig, meshctx: MeshCtx, act: str):
         from repro.models.mlp import mlp
         y = y + mlp(x, params["shared"], act)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Held-expert counters, as a serving cache carries them
+# ---------------------------------------------------------------------------
+
+COUNTERS = ("moe_rows", "moe_hits", "moe_dropped")
+
+
+def cache_counters(cache):
+    """The held-expert layers' counters in a prefill or decode cache: one
+    ``{name: array}`` per layer pattern position (arrays stacked over its
+    layers), running totals since the prefill.  Nothing is read from the
+    device, so a caller may keep them past the cache's donation."""
+    return [{k: entry[k] for k in COUNTERS}
+            for stage in cache["stages"] for entry in (stage or ())
+            if "moe_rows" in entry]
+
+
+def fold_counters(tracer, counters) -> None:
+    """Add ``cache_counters`` totals to ``tracer``'s counters ``moe.rows``
+    (rows routed to held experts), ``moe.experts_hit`` (held experts with
+    rows, summed over grouped calls) and ``moe.dropped`` (pairs routed to
+    a held expert and not computed)."""
+    for c in jax.device_get(counters):
+        tracer.count("moe.rows", int(c["moe_rows"].sum()))
+        tracer.count("moe.experts_hit", int(c["moe_hits"].sum()))
+        tracer.count("moe.dropped", int(c["moe_dropped"].sum()))

@@ -349,8 +349,9 @@ class Model:
                 for name, (shp, dt) in shapes.items():
                     full = jnp.zeros((stage.repeats,) + shp, dt)
                     got = raw[name].astype(dt)
-                    if name in ("h", "conv", "xk", "xv"):
-                        entry[name] = got
+                    if name in ("h", "conv", "xk", "xv") or name.startswith(
+                            "moe_"):
+                        entry[name] = got      # not positions of the prompt
                         continue
                     sc = shp[1]  # cache seq length for this layer kind
                     if got.shape[2] <= sc:

@@ -56,3 +56,20 @@ def test_lora_fused_compiles_for_v5e(one_chip, m):
                                                 interpret=False))
     compiled = fn.lower(sds(m, k), sds(k, n), sds(k, r), sds(r, n)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# DeepSeek-V2-Lite's held experts (8 of 64, d 2048, width 1408): a decode
+# step's rows (8 tokens × 6 picks, padded to 128) and one prefill chunk's
+# bound (16,384 tokens × 6), through the gate/up and the down projection
+@pytest.mark.parametrize("m,k,n", [(128, 2048, 1408), (128, 1408, 2048),
+                                   (98304, 2048, 1408), (98304, 1408, 2048)])
+def test_moe_gmm_compiles_for_v5e(one_chip, m, k, n):
+    from repro.kernels.moe_gmm.ops import moe_gmm
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(lambda x, w, g: moe_gmm(x, w, g, interpret=False))
+    compiled = fn.lower(sds(m, k), sds(8, k, n),
+                        sds(8, dtype=jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
