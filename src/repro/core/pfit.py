@@ -967,6 +967,7 @@ def _run_pfit_population(cfg: PFITConfig, mesh=None, client_axes=None) -> Dict:
             print(f"[pfit-pop:shepherd] round {rnd} "
                   f"cohort lm-loss {loss_per_round[-1]:.4f}")
 
+    runner.settle()       # the last round's results into the store
     if profiling:
         jax_profile_stop()
     tele.close()

@@ -1013,6 +1013,7 @@ def _run_pftt_population(cfg: PFTTConfig, mesh=None, client_axes=None) -> Dict:
                   f"sampled {sorted(int(i) for i in out['ids'])[:8]}… "
                   f"host {runner.host_overhead_frac:.1%}")
 
+    runner.settle()       # the last round's results into the store
     if profiling:
         jax_profile_stop()
     tele.close()

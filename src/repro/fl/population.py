@@ -63,6 +63,28 @@ def _writable(leaf) -> np.ndarray:
     return np.array(a, order="C")
 
 
+@dataclasses.dataclass
+class _PendingScatter:
+    """One round's results between ``PopulationStore.defer_scatter`` and
+    the write of their last row: each slot's result tree on the device
+    (``results``) and, once pulled, on the host (``host``); ``done`` marks
+    the cohort rows a gather has already written."""
+    ids: np.ndarray
+    results: Dict[str, object]
+    done: np.ndarray
+    host: Dict[str, object] = dataclasses.field(default_factory=dict)
+
+    def pulled(self, slot: str):
+        """The slot's results on the host.  ``np.asarray`` waits for the
+        copy ``defer_scatter`` started and makes no second host copy (on
+        the CPU backend it is a view of the result buffer, which nothing
+        donates)."""
+        if slot not in self.host:
+            self.host[slot] = jax.tree_util.tree_map(np.asarray,
+                                                     self.results[slot])
+        return self.host[slot]
+
+
 @dataclasses.dataclass(frozen=True)
 class PopulationConfig:
     """Population-mode knobs for ``run_pftt``/``run_pfit``.
@@ -105,12 +127,26 @@ class PopulationStore:
     single-device paths place it once.  ``scatter(slot, ids, tree)`` pulls
     the device tree to host and writes the first ``len(ids)`` rows back.
 
+    ``defer_scatter(ids, results)`` is ``PopulationRunner``'s scatter: it only
+    starts the device→host copies of a round's result trees and keeps them
+    as the *pending* scatter.  A later ``gather`` first writes the pending
+    rows of the clients it samples (so a client sampled twice in a row
+    starts from its last results); ``settle()`` writes every pending row.
+    Each read of the store (``row``, ``slots``, ``checkpoint_tree``) and
+    each other write (``scatter``, ``zero_rows``,
+    ``load_checkpoint_tree``) settles first, so none sees a row the
+    pending scatter still owes.
+
     With a ``tracer`` (``PopulationRunner`` hands the store its own), each
     gather times its ``np.take`` as a ``gather.take`` span and counts the
-    staging bytes it filled (``gather.bytes``); each scatter times the
-    device→host pull (``scatter.pull``, counter ``scatter.bytes``) and the
-    row writes (``scatter.write``) as two spans.  Without one the store
-    times and counts nothing.
+    staging bytes it filled (``gather.bytes``); the pending rows a gather
+    writes fall in a ``gather.settle`` span (counter
+    ``gather.settled_rows``).  A scatter counts the result bytes
+    (``scatter.bytes``) and times the device→host pull (``scatter.pull``)
+    and the row writes (``scatter.write``) of each slot; ``settle()``
+    puts those two inside a ``scatter`` span of its own and counts the
+    rows no gather had written (``scatter.deferred_rows``).  Without a
+    tracer the store times and counts nothing.
 
     Every leaf the store holds is host-owned, writable and C-contiguous
     (``_writable``).  ``relaid_bytes`` sums the bytes of the inputs that
@@ -124,6 +160,7 @@ class PopulationStore:
         self.relaid_bytes = 0
         self._slots = {}
         self._bufs: Dict[str, object] = {}
+        self._pending: Optional[_PendingScatter] = None
         n = None
         for name, tree in slots.items():
             tree = self._own(tree)
@@ -141,6 +178,7 @@ class PopulationStore:
 
     @property
     def slots(self) -> Dict[str, object]:
+        self.settle()
         return self._slots
 
     def _own(self, tree):
@@ -153,15 +191,19 @@ class PopulationStore:
             return _writable(a)
         return jax.tree_util.tree_map(own, tree)
 
-    def _span(self, name: str, slot: str):
+    def _span(self, name: str, **args):
         if self.tracer is None:
             return contextlib.nullcontext()
-        return self.tracer.span(name, slot=slot)
+        return self.tracer.span(name, **args)
 
     def _count(self, name: str, tree) -> None:
         if self.tracer is not None:
             self.tracer.count(name, sum(
                 leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree)))
+
+    def _count_rows(self, name: str, n: int) -> None:
+        if self.tracer is not None and n:
+            self.tracer.count(name, n)
 
     def nbytes(self) -> int:
         return sum(leaf.nbytes
@@ -172,6 +214,7 @@ class PopulationStore:
         """Rows ``ids`` of ``slot`` → the slot's reused staging buffer
         (allocated on first use, refilled in place afterwards)."""
         ids = np.asarray(ids, np.int64)
+        self._settle_rows(ids)
         k = len(ids)
         rows = max(pad_to, k)
         tree = self._slots[slot]
@@ -188,7 +231,7 @@ class PopulationStore:
             np.take(src, full, axis=0, out=dst)
             return dst
 
-        with self._span("gather.take", slot):
+        with self._span("gather.take", slot=slot):
             out = jax.tree_util.tree_map(fill, tree, buf)
         self._count("gather.bytes", out)
         return out
@@ -196,20 +239,75 @@ class PopulationStore:
     def scatter(self, slot: str, ids: np.ndarray, device_tree) -> None:
         """Write the first ``len(ids)`` rows of ``device_tree`` back into
         ``slot`` (ghost-padded rows are dropped)."""
+        self.settle()
         ids = np.asarray(ids, np.int64)
-        k = len(ids)
-        with self._span("scatter.pull", slot):
+        with self._span("scatter.pull", slot=slot):
             # np.asarray, no second host copy: the rows are copied into the
             # store below, so no view of a (later donated) jax buffer
             # outlives this call
             host = jax.tree_util.tree_map(np.asarray, device_tree)
         self._count("scatter.bytes", host)
+        with self._span("scatter.write", slot=slot):
+            self._write(slot, ids, host, slice(None, len(ids)))
 
+    def _write(self, slot: str, dst_rows, host, src_rows) -> None:
         def put(dst, src):
-            dst[ids] = src[:k]
+            dst[dst_rows] = src[src_rows]
 
-        with self._span("scatter.write", slot):
-            jax.tree_util.tree_map(put, self._slots[slot], host)
+        jax.tree_util.tree_map(put, self._slots[slot], host)
+
+    # ---- the deferred scatter ----------------------------------------------
+
+    def defer_scatter(self, ids: np.ndarray, results: Dict[str, object]):
+        """Start the device→host copies of one round's results (slot →
+        result tree, rows beyond ``len(ids)`` ghost rows) and keep them as
+        the pending scatter; nothing is written yet.  The result trees must
+        not be donated to a later computation: their host copies are read
+        until the pending scatter settles."""
+        self.settle()
+        for tree in results.values():
+            for leaf in jax.tree_util.tree_leaves(tree):
+                leaf.copy_to_host_async()
+            self._count("scatter.bytes", tree)
+        ids = np.asarray(ids, np.int64)
+        self._pending = _PendingScatter(ids, dict(results),
+                                        np.zeros(len(ids), bool))
+
+    def _settle_rows(self, ids: np.ndarray) -> None:
+        """Write the pending rows of the clients in ``ids`` (all slots)."""
+        p = self._pending
+        if p is None:
+            return
+        rows = np.where(np.isin(p.ids, ids) & ~p.done)[0]
+        if len(rows) == 0:
+            return
+        with self._span("gather.settle"):
+            for slot in p.results:
+                self._write(slot, p.ids[rows], p.pulled(slot), rows)
+        p.done[rows] = True
+        self._count_rows("gather.settled_rows", len(rows))
+
+    def settle(self) -> float:
+        """Write every row the pending scatter still owes; returns the
+        seconds its ``scatter`` span took (0.0 with nothing pending or no
+        tracer).  The write covers the whole cohort: a row a gather already
+        wrote gets the same values again, which spares an index copy of the
+        others."""
+        p = self._pending
+        if p is None:
+            return 0.0
+        self._pending = None
+        with self._span("scatter") as sp:
+            host = {}
+            for slot in p.results:
+                with self._span("scatter.pull", slot=slot):
+                    host[slot] = p.pulled(slot)
+            for slot in p.results:
+                with self._span("scatter.write", slot=slot):
+                    self._write(slot, p.ids, host[slot],
+                                slice(None, len(p.ids)))
+        self._count_rows("scatter.deferred_rows", int((~p.done).sum()))
+        return sp.dur if sp is not None else 0.0
 
     def zero_rows(self, slot: str, ids: Sequence[int]) -> None:
         """Zero the given rows (deferred crash-rejoin optimizer reset for
@@ -217,10 +315,12 @@ class PopulationStore:
         ids = np.asarray(ids, np.int64)
         if len(ids) == 0:
             return
+        self.settle()
         jax.tree_util.tree_map(lambda l: l.__setitem__(ids, 0),
                                self._slots[slot])
 
     def row(self, slot: str, i: int):
+        self.settle()
         return jax.tree_util.tree_map(lambda l: l[i], self._slots[slot])
 
     # ---- checkpointing -----------------------------------------------------
@@ -228,9 +328,11 @@ class PopulationStore:
     def checkpoint_tree(self):
         """The whole store as one pytree (slot-name-prefixed) for
         ``checkpoint.ckpt.save_checkpoint``."""
+        self.settle()
         return dict(self._slots)
 
     def load_checkpoint_tree(self, tree) -> None:
+        self.settle()
         before = self.relaid_bytes
         for name in self._slots:
             self._slots[name] = self._own(tree[name])
@@ -375,15 +477,25 @@ class PopulationRunner:
        the uploaded subtree (the downlink: participants start from the
        server's global, which also keeps the codec's delta-vs-broadcast
        reference contract), one ``device_put`` per slot;
-    4. the **fused round** runs on cohort-indexed slices of the plan;
-    5. **scatter** — result rows write back; the new global is read off any
-       cohort row whose merge gate passed (host-known from the plan).
+    4. the **fused round** is dispatched on cohort-indexed slices of the
+       plan; while the device runs it, the previous round's results are
+       written into the store (``PopulationStore.settle``), then the host
+       waits for the round;
+    5. **scatter** — the store starts the device→host copies of the
+       results and keeps them pending (``defer_scatter``): they land while
+       the caller evaluates the round, and the next round writes them,
+       the rows it samples again in its gather and the rest after its
+       dispatch.  The new global is taken, when first read, from the
+       results' row of a client whose merge gate passed (host-known from
+       the plan).
 
     Crash-rejoins that land on unsampled rounds set a ``needs_opt_reset``
     flag; the reset is applied to the store the next time that client is
     gathered.  ``state_dict``/``checkpoint_tree`` capture the whole host
     state (sampler RNG mid-stream, tracker, flags, store, global) so a
-    killed run resumes into the uninterrupted sequence."""
+    killed run resumes into the uninterrupted sequence; both settle the
+    pending scatter first, as ``settle()`` does, which a round loop calls
+    after its last round."""
 
     def __init__(self, *, pop: PopulationConfig, store: PopulationStore,
                  global_shared, upload_pred, channel, budget, ledger,
@@ -394,7 +506,7 @@ class PopulationRunner:
         self.N = pop.population
         self.K = pop.cohort_size
         self.store = store
-        self.global_shared = global_shared
+        self.global_shared = global_shared   # the setter below
         self.upload_pred = upload_pred
         self.channel = channel
         self.budget = budget
@@ -412,7 +524,7 @@ class PopulationRunner:
         self.act_bits = float(act_bits)
         self.needs_opt_reset = np.zeros(self.N, bool)
         # the tracer owns all host timing (a disabled tracer still times);
-        # host_s/round_s keep their PR 9 meaning: sample+gather+scatter vs
+        # host_s/round_s: sample+gather+scatter (both scatter spans) vs
         # whole-round wall
         self.tracer = tracer if tracer is not None else SpanTracer()
         store.tracer = self.tracer        # store spans nest in the round's
@@ -451,10 +563,22 @@ class PopulationRunner:
 
         trees.map_with_path(f, tr_buf)
 
-    def _snapshot_global(self, cid: int):
-        row = self.store.row("trainable", cid)
-        return jax.tree_util.tree_map(
-            np.array, trees.select(row, self.upload_pred))
+    @property
+    def global_shared(self):
+        """The server's global uploaded subtree (host numpy).  After a
+        round whose merge gate passed it is that round's result row of the
+        first receiving client, copied off the pulled results when first
+        read."""
+        if self._global_row is not None:
+            tree, j = self._global_row
+            self._global_row = None
+            self._global = jax.tree_util.tree_map(
+                lambda l: np.array(np.asarray(l)[j], order="C"), tree)
+        return self._global
+
+    @global_shared.setter
+    def global_shared(self, tree) -> None:
+        self._global, self._global_row = tree, None
 
     # ---- the round ---------------------------------------------------------
 
@@ -536,6 +660,9 @@ class PopulationRunner:
                             * (self.n_rows - self.K))
                     outs = round_step(tr_d, opt_d, pend_d, batches, *margs,
                                       self._put(ck))
+                # the last round's results go into the store while the
+                # device runs this one (its own "scatter" span)
+                scatter_s = self.store.settle()
                 with tracer.span("device-step.wait"):
                     jax.block_until_ready(outs)
                 tr_d, opt_d, pend_d, losses = outs[:4]
@@ -549,17 +676,19 @@ class PopulationRunner:
                     hstats = outs[-1]
 
             with tracer.span("scatter") as sp_scatter:
-                self.store.scatter("trainable", ids, tr_d)
-                self.store.scatter("opt", ids, opt_d)
-                self.store.scatter("pending", ids, pend_d)
-                # the merge gate is host-known: extract the new global from
-                # any cohort row that received the broadcast
+                # outputs of round_step, which donates only its inputs
+                self.store.defer_scatter(ids, {"trainable": tr_d,
+                                               "opt": opt_d,
+                                               "pending": pend_d})
+                # the merge gate is host-known: the new global is any
+                # cohort row that received the broadcast
                 gate = float(rplan.agg_w.sum()) > 0 and rplan.quorum_ok
                 if gate:
                     recv_rows = np.where(rplan.recv[ids] > 0)[0]
                     if len(recv_rows):
-                        self.global_shared = self._snapshot_global(
-                            int(ids[recv_rows[0]]))
+                        self._global_row = (
+                            trees.select(tr_d, self.upload_pred),
+                            int(recv_rows[0]))
 
             with tracer.span("ledger"):
                 fresh_n = np.zeros(self.N, np.float64)
@@ -588,7 +717,8 @@ class PopulationRunner:
                         for ci in att]
                 self.ledger.log_round(reports, extra, round_id=rnd)
 
-        self.host_s += sp_sample.dur + sp_gather.dur + sp_scatter.dur
+        self.host_s += (sp_sample.dur + sp_gather.dur + scatter_s
+                        + sp_scatter.dur)
         self.round_s += sp_round.dur
         self.round_wall.append(sp_round.dur)
         if hstats is not None:
@@ -604,9 +734,14 @@ class PopulationRunner:
             if self.arrivals is not None:
                 self.arrivals.burn_round()
 
+    def settle(self) -> None:
+        """Write the last round's pending results into the store."""
+        self.host_s += self.store.settle()
+
     # ---- checkpoint/resume -------------------------------------------------
 
     def state_dict(self) -> Dict:
+        self.settle()
         d = {"sampler": self.sampler.state_dict(),
              "tracker": self.tracker.state_dict(),
              "needs_opt_reset": np.where(self.needs_opt_reset)[0].tolist(),
@@ -630,6 +765,7 @@ class PopulationRunner:
             self.est_bits = np.asarray(d["est_bits"], np.float64)
 
     def checkpoint_tree(self):
+        self.settle()
         return {"store": self.store.checkpoint_tree(),
                 "global": self.global_shared}
 

@@ -31,24 +31,31 @@ Span-name convention (used by every runner; see docs/observability.md):
     sample            cohort sampling (population) / host batch draw (cohort)
     plan              StalenessTracker round plan (population)
     gather            store gather + global overlay + device_put / batch stack
+      gather.settle   the last round's pending rows of the clients sampled
+                      again, written first; counter gather.settled_rows
       gather.take     one store slot's np.take into its staging buffer
                       (args: slot); counter gather.bytes
     encode            codec PRNG key build (host side of the compressed uplink)
     device-step       the ONE fused compiled round dispatch + its block
       device-step.draw   the cohort's batch draw, ghost rows and stacking
+      scatter         the last round's results written into the store while
+                      the device runs this round (PopulationStore.settle);
+                      counter scatter.deferred_rows
+        scatter.pull  the wait for one slot's started device→host copies
+                      (args: slot)
+        scatter.write the row writes of one slot into the store (args: slot)
       device-step.wait   block_until_ready on the round's outputs
-    scatter           device→store writeback + global snapshot
-      scatter.pull    device→host copies of one slot's results (args: slot);
-                      counter scatter.bytes
-      scatter.write   the row writes of one slot into the store (args: slot)
+    scatter           this round's device→host copies started and kept
+                      pending + the new global's row noted; counter
+                      scatter.bytes
     ledger            channel reports + CommLedger append
     eval              fused cohort eval dispatch
     checkpoint        round-level checkpoint save
 
-The dotted children are the population runner's
+The dotted children are the population runner's and its store's
 (``fl/population.py``); a parent's self time (the parent less its
 children) is what is left: zeroing, the global overlay, the
-``device_put`` enqueues, the dispatch, the global snapshot.
+``device_put`` enqueues, the dispatch, the start of the result copies.
 
 ``chrome_trace()``/``write()`` emit the standard
 ``{"traceEvents": [...]}`` JSON object format: load the file in

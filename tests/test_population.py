@@ -402,16 +402,21 @@ def test_sampled_round_matches_standalone_cohort():
 # child spans and byte counters of the round driver and the store
 # ---------------------------------------------------------------------------
 
-CHILD_SPANS = {"gather.take": "gather", "device-step.draw": "device-step",
+CHILD_SPANS = {"gather.take": "gather", "gather.settle": "gather",
+               "device-step.draw": "device-step",
                "device-step.wait": "device-step", "scatter.pull": "scatter",
                "scatter.write": "scatter"}
 
 
 def _toy_runner(N=12, K=3, rounds=3, tracer=None, n_rows=None,
-                fortran=False):
+                fortran=False, donate=False, record=None, devices=1):
     """A PopulationRunner over the toy cohort: robust round step, Rayleigh
-    uplink, no faults; ``n_rows`` > K adds ghost rows; ``fortran`` hands
-    the store column-major trainable and optimizer leaves."""
+    uplink, no faults; ``n_rows`` > K adds ghost rows (the cohort sharded
+    over a ``("data",)`` mesh of ``devices`` devices); ``fortran`` hands
+    the store column-major trainable and optimizer leaves; ``donate``
+    builds the round step that donates its stacked inputs, as the
+    program's does; ``record`` (a list) receives host copies of each
+    round step call's batches and fault masks."""
     from repro.comms import ChannelBudget
     from repro.core.cohort import HostBatchStacker, build_supervised_round
     from repro.core.robust import StalenessConfig, StalenessTracker
@@ -434,7 +439,7 @@ def _toy_runner(N=12, K=3, rounds=3, tracer=None, n_rows=None,
                              "pending": pend})
     channel = RayleighChannel(seed=3)
     cs = None if n_rows is None else CohortSharding(
-        mesh=auto_mesh((1,), ("data",)), axes=("data",), n_clients=K,
+        mesh=auto_mesh((devices,), ("data",)), axes=("data",), n_clients=K,
         total=n_rows)
     runner = PopulationRunner(
         pop=PopulationConfig(population=N, cohort_size=K),
@@ -448,8 +453,14 @@ def _toy_runner(N=12, K=3, rounds=3, tracer=None, n_rows=None,
         strace=Scenario().realize(N, rounds),
         sampler=ClientSampler("uniform", N, K, seed=7), cs=cs,
         tracer=tracer)
-    step = build_supervised_round(local_step, upload, donate=False,
+    step = build_supervised_round(local_step, upload, donate=donate,
                                   robust=True)
+    if record is not None:
+        inner = step
+
+        def step(*args):
+            record.append(jax.tree_util.tree_map(np.array, args[3:]))
+            return inner(*args)
 
     def draw(cid, rnd):
         r = np.random.RandomState(100 * cid + rnd)
@@ -464,21 +475,31 @@ def _toy_runner(N=12, K=3, rounds=3, tracer=None, n_rows=None,
 
 
 def test_run_round_records_child_spans_inside_parents():
-    runner, _, one_round = _toy_runner(tracer=SpanTracer(enabled=True))
+    """Four of six clients a round, so consecutive cohorts always share
+    clients and every round after the first settles some in its gather.
+    A round writes the previous round's results, so round 0 has no
+    ``scatter.pull``/``scatter.write`` and the last round's come with
+    ``settle()``."""
+    runner, _, one_round = _toy_runner(N=6, K=4,
+                                       tracer=SpanTracer(enabled=True))
     tracer = runner.tracer
-    for rnd in range(2):
+    for rnd in range(3):
         one_round(rnd)
         phases = tracer.pop_round()
-        assert set(CHILD_SPANS) <= set(phases)
+        want = set(CHILD_SPANS) - (
+            {"gather.settle", "scatter.pull", "scatter.write"}
+            if rnd == 0 else set())
+        assert want <= set(phases)
         for parent in set(CHILD_SPANS.values()):
             kids = sum(v for k, v in phases.items()
                        if CHILD_SPANS.get(k) == parent)
             assert kids <= phases[parent]
+    runner.settle()
     # one gather.take, scatter.pull and scatter.write per slot and round
     ev = tracer.chrome_trace()["traceEvents"]
     for name in ("gather.take", "scatter.pull", "scatter.write"):
         slots = [e["args"]["slot"] for e in ev if e["name"] == name]
-        assert slots == ["trainable", "opt", "pending"] * 2
+        assert slots == ["trainable", "opt", "pending"] * 3
     # every child lies inside one of its parent's events
     spans = [e for e in ev if e["ph"] == "X"]
     for c in spans:
@@ -513,7 +534,9 @@ def test_run_round_byte_counters(n_rows):
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(
             store.slots["trainable"])) // store.n_clients
     one_round(1)
-    assert runner.tracer.counts() == {k: 2 * v for k, v in counts.items()}
+    got = runner.tracer.counts()
+    assert {k: got[k] for k in counts} == {k: 2 * v
+                                          for k, v in counts.items()}
 
 
 @pytest.mark.parametrize("fortran", [False, True])
@@ -540,11 +563,13 @@ def test_run_round_store_identical_for_any_input_layout():
     may be laid out; the pending slot from ``np.zeros_like`` of them,
     writable) leave a store bit-identical to the C-ordered one's, every
     leaf C-contiguous."""
-    _, fort, one_a = _toy_runner(fortran=True)
-    _, plain, one_b = _toy_runner()
+    run_a, fort, one_a = _toy_runner(fortran=True)
+    run_b, plain, one_b = _toy_runner()
     for rnd in range(3):
         one_a(rnd)
         one_b(rnd)
+    run_a.settle()
+    run_b.settle()
     for slot in ("trainable", "opt", "pending"):
         _assert_host_c(fort, slot)
         a = trees.flatten(fort.slots[slot])
@@ -557,17 +582,285 @@ def test_run_round_store_identical_for_any_input_layout():
 def test_run_round_store_identical_with_and_without_tracing():
     """Tracing changes nothing the store holds: an enabled tracer's round
     and a default (disabled) one's leave bit-identical stores."""
-    _, traced, one_a = _toy_runner(tracer=SpanTracer(enabled=True))
-    _, plain, one_b = _toy_runner()
+    run_a, traced, one_a = _toy_runner(tracer=SpanTracer(enabled=True))
+    run_b, plain, one_b = _toy_runner()
     for rnd in range(3):
         one_a(rnd)
         one_b(rnd)
+    run_a.settle()
+    run_b.settle()
     for slot in ("trainable", "opt", "pending"):
         a = trees.flatten(traced.slots[slot])
         b = trees.flatten(plain.slots[slot])
         assert a.keys() == b.keys()
         for k in a:
             np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+# ---------------------------------------------------------------------------
+# the deferred scatter: a round's results are written during the next round
+# ---------------------------------------------------------------------------
+
+UPLOAD = lambda p: p.startswith("shared")
+
+
+def _settled_copy(store):
+    """The store's slots as ``settle()`` would leave them, read without
+    settling, so the next round still finds its pending scatter."""
+    out = {k: jax.tree_util.tree_map(np.array, v)
+           for k, v in store._slots.items()}
+    p = store._pending
+    if p is not None:
+        k = len(p.ids)
+        for slot, res in p.results.items():
+            jax.tree_util.tree_map(
+                lambda dst, src: dst.__setitem__(p.ids, np.asarray(src)[:k]),
+                out[slot], res)
+    return out
+
+
+def _serial_rounds(N, K, n_rows, rounds, calls, plans, donate, devices):
+    """The serial order, written from ``PopulationStore.gather``/``scatter``
+    and the round step alone: each round's results are in the store before
+    the next round gathers.  The batches and fault masks are the runner's
+    (``calls``), the merge gates its plans'.  Yields per round the cohort,
+    the store, the global and the losses."""
+    from repro.core.cohort import build_supervised_round
+    ref_run, store, _ = _toy_runner(N=N, K=K, rounds=rounds, n_rows=n_rows,
+                                    devices=devices)
+    local_step = _toy_cohort(N)[0]
+    step = build_supervised_round(local_step, UPLOAD, donate=donate,
+                                  robust=True)
+    put = jax.device_put if n_rows is None else \
+        (lambda t: jax.device_put(t, ref_run.cs.named))
+    sampler = ClientSampler("uniform", N, K, seed=7)
+    glob = jax.tree_util.tree_map(
+        np.array, trees.select(store.row("trainable", 0), UPLOAD))
+    for rnd in range(rounds):
+        ids = sampler.sample()
+        tr = store.gather("trainable", ids, pad_to=n_rows or K)
+        flat_g = trees.flatten(glob)
+        for path, leaf in trees.flatten(tr).items():
+            if flat_g.get(path) is not None:
+                leaf[:] = flat_g[path]
+        op = store.gather("opt", ids, pad_to=n_rows or K)
+        pd = store.gather("pending", ids, pad_to=n_rows or K)
+        batches, margs = calls[rnd][0], calls[rnd][1:]
+        outs = step(put(tr), put(op), put(pd), jax.device_put(batches),
+                    *[put(m) for m in margs])
+        for slot, res in zip(("trainable", "opt", "pending"), outs[:3]):
+            store.scatter(slot, ids, res)
+        plan = plans[rnd]
+        if float(plan.agg_w.sum()) > 0 and plan.quorum_ok:
+            recv_rows = np.where(plan.recv[ids] > 0)[0]
+            if len(recv_rows):
+                glob = jax.tree_util.tree_map(np.array, trees.select(
+                    store.row("trainable", int(ids[recv_rows[0]])), UPLOAD))
+        yield (ids, jax.tree_util.tree_map(np.array, store.slots), glob,
+               np.array(outs[3]))
+
+
+def _assert_trees_equal(a, b):
+    fa, fb = trees.flatten(a), trees.flatten(b)
+    assert fa.keys() == fb.keys()
+    for k in fa:
+        np.testing.assert_array_equal(fa[k], fb[k], err_msg=k)
+
+
+@pytest.mark.parametrize("donate", [False, True])
+@pytest.mark.parametrize("n_rows", [None, 6])
+def test_deferred_scatter_matches_serial_order(n_rows, donate):
+    """Four of six clients a round (consecutive cohorts always share
+    clients), with and without ghost rows, with the round step donating
+    its inputs or not: after every round the settled store, the global
+    and the losses are bit-identical to the serial order, and every
+    result row is written once, by the next gather (the clients sampled
+    again) or after the next dispatch (the rest)."""
+    _check_serial_order(n_rows, donate)
+
+
+def _check_serial_order(n_rows, donate, devices=1):
+    N, K, R = 6, 4, 7
+    calls = []
+    runner, store, one_round = _toy_runner(N=N, K=K, rounds=R,
+                                           n_rows=n_rows, donate=donate,
+                                           record=calls, devices=devices)
+    got = []
+    for rnd in range(R):
+        out = one_round(rnd)
+        got.append((out["ids"], _settled_copy(store), runner.global_shared,
+                    np.array(out["losses"]), out["plan"]))
+        assert store._pending is not None          # nothing written yet
+    runner.settle()
+    ref = list(_serial_rounds(N, K, n_rows, R, calls,
+                              [g[4] for g in got], donate, devices))
+    for (ids, slots, glob, losses, _), (r_ids, r_slots, r_glob, r_losses) \
+            in zip(got, ref):
+        np.testing.assert_array_equal(ids, r_ids)
+        _assert_trees_equal(slots, r_slots)
+        _assert_trees_equal(glob, r_glob)
+        np.testing.assert_array_equal(losses, r_losses)
+    _assert_trees_equal(store.slots, ref[-1][1])
+    inter = [len(np.intersect1d(a[0], b[0])) for a, b in zip(got, got[1:])]
+    assert min(inter) > 0
+    counts = runner.tracer.counts()
+    assert counts["gather.settled_rows"] == sum(inter)
+    # the last round's rows all came with settle()
+    assert counts["scatter.deferred_rows"] == sum(K - i for i in inter) + K
+
+
+@pytest.mark.parametrize("n_rows", [None, 6])
+def test_deferred_scatter_resume_matches_uninterrupted(n_rows):
+    """A runner checkpointed (``state_dict`` through JSON, as the sidecar
+    holds it, and ``checkpoint_tree``) after round 3 and resumed into a
+    fresh runner ends with a store and global bit-identical to an
+    uninterrupted run's, whose rounds never settled in between."""
+    _check_resume(n_rows)
+
+
+def _check_resume(n_rows, devices=1):
+    import json
+    N, K, R, cut = 6, 4, 7, 3
+    kw = dict(N=N, K=K, rounds=R, n_rows=n_rows, devices=devices)
+    full, f_store, one_f = _toy_runner(**kw)
+    for rnd in range(R):
+        one_f(rnd)
+    a, _, one_a = _toy_runner(**kw)
+    for rnd in range(cut):
+        one_a(rnd)
+    state = json.loads(json.dumps(a.state_dict()))
+    tree = jax.tree_util.tree_map(np.array, a.checkpoint_tree())
+    b, b_store, one_b = _toy_runner(**kw)
+    b.load_state_dict(state)
+    b.load_checkpoint_tree(tree)
+    b.burn_rounds(cut)
+    for rnd in range(cut, R):
+        one_b(rnd)
+    _assert_trees_equal(b_store.slots, f_store.slots)
+    _assert_trees_equal(b.global_shared, full.global_shared)
+
+
+SHARDED_SUBPROC = """
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+sys.path.insert(0, "tests")
+import test_population as tp
+for donate in (False, True):
+    tp._check_serial_order(8, donate, devices=4)
+tp._check_resume(8, devices=4)
+print("SHARDED_OK")
+"""
+
+
+@pytest.mark.multidevice
+def test_deferred_scatter_sharded_4dev_matches_serial_order():
+    """The cohort sharded over four devices, four real rows and four
+    ghost rows: the deferred scatter drops the ghost rows and is
+    bit-identical to the serial order, and a resume to an uninterrupted
+    run (subprocess, so the forced device count stays there)."""
+    import os
+    import subprocess
+    import sys
+    proc = subprocess.run([sys.executable, "-c", SHARDED_SUBPROC],
+                          capture_output=True, text=True, timeout=900,
+                          env={**os.environ, "PYTHONPATH": "src"})
+    assert "SHARDED_OK" in proc.stdout, (proc.stdout, proc.stderr[-3000:])
+
+
+def test_pending_scatter_holds_no_view_of_reused_buffers():
+    """On the CPU backend ``np.asarray`` of a result is a view of its
+    buffer.  The round step donates its inputs, not its outputs, and the
+    staging buffers are refilled in place every round: the pending
+    results share memory with no staging buffer and keep their values
+    through the next round's refill and donation, which come before its
+    deferred write."""
+    runner, store, one_round = _toy_runner(N=6, K=4, donate=True)
+    one_round(0)
+    p = store._pending
+    host = {slot: p.pulled(slot) for slot in p.results}
+    before = jax.tree_util.tree_map(np.array, host)
+    staging = jax.tree_util.tree_leaves(store._bufs)
+    for leaf in jax.tree_util.tree_leaves(host):
+        assert not any(np.shares_memory(leaf, buf) for buf in staging)
+    one_round(1)
+    _assert_trees_equal(host, before)
+
+
+def _deferred_store(n=8):
+    """A toy store with new values pending for rows 1, 3 and 5 (one ghost
+    row after them)."""
+    store, ref = _toy_store(n)
+    store.tracer = SpanTracer()
+    ids = np.asarray([1, 3, 5])
+    r = np.random.RandomState(2)
+    dev = jax.tree_util.tree_map(
+        lambda l: jnp.asarray(r.randn(4, *l.shape[1:]).astype(l.dtype)), ref)
+    store.defer_scatter(ids, {"trainable": dev})
+    want = jax.tree_util.tree_map(np.array, ref)
+    for k, leaf in trees.flatten(want).items():
+        leaf[ids] = np.asarray(trees.flatten(dev)[k])[:3]
+    return store, ref, want
+
+
+def _load_other(store):
+    """Load a checkpoint of other values; returns them."""
+    other, _ = _toy_store(store.n_clients, seed=5)
+    tree = jax.tree_util.tree_map(np.array, other.slots["trainable"])
+    store.load_checkpoint_tree({"trainable": tree})
+    return tree
+
+
+SETTLING_CALLS = {
+    "row": lambda s: s.row("trainable", 0),
+    "slots": lambda s: s.slots,
+    "checkpoint_tree": lambda s: s.checkpoint_tree(),
+    "zero_rows": lambda s: s.zero_rows("trainable", [0]),
+    "scatter": lambda s: s.scatter("trainable", np.asarray([0]),
+                                   jax.tree_util.tree_map(
+                                       lambda l: jnp.asarray(l[:1]),
+                                       s._slots["trainable"])),
+    "load_checkpoint_tree": _load_other,
+    "settle": lambda s: s.settle(),
+}
+
+
+@pytest.mark.parametrize("call", sorted(SETTLING_CALLS))
+def test_store_call_settles_pending_scatter_first(call):
+    """Every read of the store and every other write finishes the pending
+    scatter first: after the call no row is owed, and the store holds the
+    pending rows (row 0, which each call may touch, aside), or all of a
+    loaded checkpoint's, which no pending row overwrites later."""
+    store, _, want = _deferred_store()
+    loaded = SETTLING_CALLS[call](store)
+    assert store._pending is None
+    if call == "load_checkpoint_tree":
+        want = loaded
+    for k, leaf in trees.flatten(want).items():
+        np.testing.assert_array_equal(
+            trees.flatten(store._slots["trainable"])[k][1:], leaf[1:])
+    assert store.tracer.counts()["scatter.deferred_rows"] == 3
+
+
+def test_store_gather_settles_only_sampled_pending_rows():
+    """A gather writes the pending rows of the clients it samples, and
+    only those, before it reads; ``settle`` writes the rest."""
+    store, ref, want = _deferred_store()
+    g = store.gather("trainable", np.asarray([3, 4]))
+    for k, leaf in trees.flatten(g).items():
+        np.testing.assert_array_equal(leaf, trees.flatten(want)[k][[3, 4]])
+    flat = trees.flatten(store._slots["trainable"])
+    for k, leaf in trees.flatten(ref).items():
+        np.testing.assert_array_equal(flat[k][[1, 5]], leaf[[1, 5]])
+    assert store.tracer.counts()["gather.settled_rows"] == 1
+    assert "scatter.deferred_rows" not in store.tracer.counts()
+    store.gather("trainable", np.asarray([3, 4]))   # nothing left to settle
+    assert store.tracer.counts()["gather.settled_rows"] == 1
+    store.settle()
+    _assert_trees_equal(store._slots["trainable"], want)
+    assert store.tracer.counts()["scatter.deferred_rows"] == 2
+    assert {"gather.settle", "scatter", "scatter.pull",
+            "scatter.write"} <= set(store.tracer.totals())
 
 
 def test_store_scatter_pull_then_write_matches_per_leaf_copy():
